@@ -28,6 +28,9 @@ def main() -> None:
                          "without the full measurement cost")
     args = ap.parse_args()
 
+    from repro.launch.runtime import enable_compile_cache
+    enable_compile_cache()
+
     from benchmarks.decode_bench import ALL_DECODE_BENCHES, decode_bench
     from benchmarks.kernel_bench import ALL_KERNEL_BENCHES
     from benchmarks.paper_figures import ALL_FIGURES

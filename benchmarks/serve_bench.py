@@ -607,16 +607,21 @@ def serve_bench_sharded(arch: str = "smollm_135m", n_requests: int = 16,
                         max_new: int = 16, seed: int = 0,
                         buckets: Tuple[int, ...] = (8, 16, 32),
                         mesh_spec: str = "2x2", devices: int = 4):
-    """Mesh-sharded serve bench: spawns a subprocess with ``devices`` forced
-    host devices (the calling process' jax stays single-device) and parses
-    its CSV rows.  Registered in ``benchmarks.run`` as ``serve_sharded``."""
+    """Mesh-sharded serve bench: spawns a CPU subprocess with ``devices``
+    forced host devices (the calling process' jax stays single-device) and
+    parses its CSV rows.  Registered in ``benchmarks.run`` as
+    ``serve_sharded``.  Refused when this process holds a TPU: the child
+    could not open the chip, and a CPU child's timings are no chip
+    measurement."""
+    from repro.launch.runtime import cpu_child_env, refuse_if_chip_held
+
+    refuse_if_chip_held("serve_bench_sharded")
     args = ["--child-sharded", "--arch", arch,
             "--requests", str(n_requests), "--max-slots", str(max_slots),
             "--tick-steps", str(tick_steps), "--new-tokens", str(max_new),
             "--seed", str(seed), "--mesh", mesh_spec,
             "--buckets", ",".join(str(b) for b in buckets)]
-    env = dict(
-        os.environ,
+    env = cpu_child_env(
         XLA_FLAGS=f"--xla_force_host_platform_device_count={devices}",
         PYTHONPATH=os.pathsep.join(
             [_REPO, os.path.join(_REPO, "src"),
@@ -804,6 +809,9 @@ def main(argv=None) -> None:
                     help=argparse.SUPPRESS)   # internal: runs inside the
                                               # forced-device subprocess
     args = ap.parse_args(argv)
+
+    from repro.launch.runtime import enable_compile_cache
+    enable_compile_cache()
 
     buckets = (tuple(int(b) for b in args.buckets.split(","))
                if args.buckets else (8, 16, 32))

@@ -30,7 +30,7 @@ from typing import Iterator, List, Optional
 
 import jax
 import numpy as np
-from jax import core
+from jax.extend import core
 
 from repro.analysis.report import Finding
 
@@ -84,6 +84,7 @@ CALLBACK_PRIMS = frozenset(
         "pure_callback",
         "io_callback",
         "debug_callback",
+        "debug_print",
         "outside_call",
         "host_callback_call",
         "infeed",

@@ -62,7 +62,8 @@ VMEM_LIMIT_BYTES = 16 * 2**20
 VMEM_BYTES_RTOL = 0.10
 TICK_BYTES_RTOL = 0.10
 
-# kernel-body function name (as pallas records it in name_and_src_info)
+# kernel-body function name (as pallas records it in the kernel jaxpr's
+# debug info)
 # -> audit family; the per-tick census keys sites by this
 KERNEL_FN_FAMILY = {
     "_paged_attn_kernel": "paged_attention",
@@ -194,20 +195,18 @@ def _traffic_paged(inst: KernelInstantiation) -> Tuple[Dict, List[Finding]]:
     table = np.asarray(meta["table"])
     lens = np.asarray(meta["lengths"])
     page_len, bps = int(meta["page_len"]), int(meta["bps"])
-    g = inst.inputs[0].shape[1]
 
     def live(name: str, gidx: Tuple[int, ...]) -> bool:
         if name not in ("k_pool", "v_pool", "k_scale", "v_scale"):
             return True
-        bi, _, si, ji = gidx
+        bi, si, ji = gidx
         return si * bps + ji < -(-int(lens[bi]) // page_len)
 
     tr = block_traffic(inst, live=live)
 
     # the static gather fraction: pages the table walk touches, per slot
-    # (each group re-reads the same pages — divide the g multiplicity out)
-    assert tr["fetches"]["k_pool"] % g == 0
-    static_touched = tr["fetches"]["k_pool"] // g
+    # (one fetch per page: a block carries every kv head of its page)
+    static_touched = tr["fetches"]["k_pool"]
     total = table.shape[0] * table.shape[1]
     saved_frac = 1.0 - static_touched / total
 

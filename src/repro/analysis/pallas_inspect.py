@@ -307,7 +307,7 @@ class PallasCallSite:
 
 
 def _jaxprs_of(v):
-    from jax import core
+    from jax.extend import core
 
     if isinstance(v, core.ClosedJaxpr):
         yield v.jaxpr
@@ -323,14 +323,17 @@ def extract_pallas_calls(jaxpr, _mult: int = 1) -> List[PallasCallSite]:
     enclosing ``scan`` trip counts multiplied through (``while`` bodies are
     scaled x1 — trip counts are data-dependent; the serve tick's loops are
     fixed-length scans, so the census is exact where it matters)."""
-    from jax import core
+    from jax.extend import core
 
     jaxpr = jaxpr.jaxpr if isinstance(jaxpr, core.ClosedJaxpr) else jaxpr
     out: List[PallasCallSite] = []
     for eqn in jaxpr.eqns:
         if eqn.primitive.name == "pallas_call":
             gm = eqn.params["grid_mapping"]
-            name = str(eqn.params["name_and_src_info"].name)
+            # the kernel body's function name, from the kernel jaxpr's
+            # debug info ("<name> at <file>:<line>")
+            src = eqn.params["jaxpr"].debug_info.func_src_info
+            name = src.split(" ")[0]
             avals = [v.aval for v in eqn.invars]
             out.append(
                 PallasCallSite(
@@ -340,7 +343,8 @@ def extract_pallas_calls(jaxpr, _mult: int = 1) -> List[PallasCallSite]:
                     operand_shapes=tuple(tuple(int(s) for s in a.shape) for a in avals),
                     operand_dtypes=tuple(_np_dtype(a.dtype).name for a in avals),
                     block_shapes=tuple(
-                        tuple(int(b) for b in bm.block_shape) for bm in gm.block_mappings
+                        tuple(int(getattr(b, "block_size", b)) for b in bm.block_shape)
+                        for bm in gm.block_mappings
                     ),
                 )
             )

@@ -134,10 +134,10 @@ def run_recompile_audit() -> Tuple[List[Finding], Dict[str, int]]:
     # mesh-fingerprint collision check: same configuration, unsharded vs a
     # degenerate 1x1 mesh — two distinct generate-LRU entries (building the
     # jitted wrappers compiles nothing)
-    import jax
+    from repro.launch.mesh import auto_mesh
 
     fp_none = engine.mesh_fingerprint(None)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = auto_mesh((1, 1), ("data", "model"))
     fp_mesh = engine.mesh_fingerprint(mesh)
     if fp_none == fp_mesh:
         findings.append(

@@ -25,7 +25,7 @@ from __future__ import annotations
 
 from typing import Dict, List, Optional, Tuple
 
-from jax import core
+from jax.extend import core
 
 from repro.analysis.jaxpr_rules import _as_jaxpr, sub_jaxprs
 from repro.analysis.report import Finding

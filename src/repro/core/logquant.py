@@ -36,6 +36,7 @@ from __future__ import annotations
 
 from typing import NamedTuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -214,6 +215,14 @@ def quantize_page_codes(x: jnp.ndarray, scale_exp: jnp.ndarray,
     return pack_codes(LogQuantized(exp=q.exp, sign=sign), n_bits)
 
 
+def pow2_exact(e: jnp.ndarray) -> jnp.ndarray:
+    """``2^e`` in f32 for int32 ``e`` in the normal range [-126, 127],
+    built from the exponent field: exact on every backend, where ``exp2``
+    may round, or flush ``2^-126`` to zero."""
+    return jax.lax.bitcast_convert_type((e.astype(jnp.int32) + 127) << 23,
+                                        jnp.float32)
+
+
 def dequantize_page_codes(codes: jnp.ndarray, scale_exp: jnp.ndarray,
                           n_bits: int = 4,
                           dtype: jnp.dtype = jnp.float32) -> jnp.ndarray:
@@ -227,7 +236,7 @@ def dequantize_page_codes(codes: jnp.ndarray, scale_exp: jnp.ndarray,
     sentinel = zero_sentinel(n_bits)
     e = jnp.clip(q.exp.astype(jnp.int32) + scale_exp.astype(jnp.int32),
                  -126, 127)
-    val = q.sign.astype(jnp.float32) * jnp.exp2(e.astype(jnp.float32))
+    val = q.sign.astype(jnp.float32) * pow2_exact(e)
     return jnp.where(q.exp == sentinel, 0.0, val).astype(dtype)
 
 

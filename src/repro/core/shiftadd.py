@@ -41,6 +41,7 @@ from __future__ import annotations
 import dataclasses
 from typing import List, NamedTuple, Optional, Tuple, Union
 
+import jax
 import jax.numpy as jnp
 
 from repro.core import bitplane as bp
@@ -207,7 +208,8 @@ def quantized_linear_apply(p: QuantizedLinearParams, x: jnp.ndarray,
                            n_bits: int = 4,
                            truncated: bool = True,
                            backend: str = "xla",
-                           collect: Optional[list] = None) -> jnp.ndarray:
+                           collect: Optional[list] = None,
+                           tp: Optional[str] = None) -> jnp.ndarray:
     """x (..., K) -> y (..., N) through the full QeiHaN path.
 
     ``p.planes`` may be packed 8-to-a-byte along K (the HBM-resident deploy
@@ -217,8 +219,18 @@ def quantized_linear_apply(p: QuantizedLinearParams, x: jnp.ndarray,
     ``backend="pallas"`` runs the plane-skipping Pallas kernel instead of the
     unrolled jnp bit-plane matmul (identical int32 result); ``collect``
     accumulates ``(fetched, total)`` plane-tile traffic counts (see
-    :class:`QuantCtx`).
+    :class:`QuantCtx`).  ``tp`` (``"col"`` / ``"row"``) is the weight's
+    tensor-parallel layout, which the kernel needs under a mesh.
+
+    ``x`` and the result pass optimization barriers.  XLA may keep a fused
+    producer's or consumer's excess precision (f32 where the dtype says
+    bf16), and how much depends on how each backend's program fuses; a
+    4-bit log2 code turns that difference into different tokens (on a TPU
+    v5e, bf16 smollm-135m served different tokens per backend).  At the
+    barriers the values are the ones their dtypes name, whatever the
+    backend.
     """
+    x = jax.lax.optimization_barrier(x)
     lead = x.shape[:-1]
     k = x.shape[-1]
     planes = p.planes
@@ -242,7 +254,7 @@ def quantized_linear_apply(p: QuantizedLinearParams, x: jnp.ndarray,
         if backend == "pallas":
             from repro.kernels.bitplane_matmul.ops import bitplane_matmul_pallas
             y_int = bitplane_matmul_pallas(q.exp, q.sign, planes,
-                                           n_bits=n_bits)
+                                           n_bits=n_bits, tp=tp)
         else:
             y_int = shiftadd_matmul_bitplane(q, planes, n_bits=n_bits)
         y = y_int.astype(jnp.float32)
@@ -253,4 +265,4 @@ def quantized_linear_apply(p: QuantizedLinearParams, x: jnp.ndarray,
     y = y.reshape(*lead, -1)
     if p.bias is not None:
         y = y + p.bias
-    return y
+    return jax.lax.optimization_barrier(y)

@@ -45,10 +45,6 @@ from jax.experimental.pallas import tpu as pltpu
 
 WEIGHT_BITS = 8
 
-# jax renamed TPUCompilerParams -> CompilerParams around 0.5; support both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
-
 
 def _bitplane_matmul_kernel(min_plane_ref,          # scalar prefetch (Mb, Kb)
                             exp_ref, sign_ref,       # (bm, bk) int8
@@ -78,7 +74,8 @@ def _bitplane_matmul_kernel(min_plane_ref,          # scalar prefetch (Mb, Kb)
             a_b = jnp.where(alive & (sh >= 0),
                             (s << jnp.clip(sh, 0, 14)).astype(jnp.float32),
                             0.0)
-            p_b = planes_ref[b].astype(jnp.float32)
+            # Mosaic has no direct uint8 -> f32 convert; go through int32
+            p_b = planes_ref[b].astype(jnp.int32).astype(jnp.float32)
             term = jax.lax.dot_general(
                 a_b, p_b, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
@@ -137,7 +134,7 @@ def bitplane_matmul_kernel(exp: jnp.ndarray, sign: jnp.ndarray,
         kern,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(min_plane, exp, sign, planes)

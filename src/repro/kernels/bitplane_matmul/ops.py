@@ -15,6 +15,7 @@ import jax.numpy as jnp
 
 from repro.kernels.bitplane_matmul.kernel import (WEIGHT_BITS,
                                                   bitplane_matmul_kernel)
+from repro.models.sharding import kernel_call, kernel_mesh
 
 
 def _skip_table(exp: jnp.ndarray, block_m: int, block_k: int,
@@ -33,16 +34,50 @@ def _skip_table(exp: jnp.ndarray, block_m: int, block_k: int,
         jnp.int32)
 
 
-@functools.partial(jax.jit, static_argnames=("n_bits", "block_m", "block_n",
-                                             "block_k", "interpret"))
+# per-dim roles (models.sharding.kernel_call) of (exp, sign, planes) -> out
+# for each tensor-parallel weight layout: column-parallel weights hold N on
+# the model axis, row-parallel ones K (their partial sums reduce over it)
+_TP_LAYOUTS = {
+    None: (((None, None), (None, None), (None, None, None)), (None, None)),
+    "col": (((None, None), (None, None), (None, None, "m")), (None, "m")),
+    "row": (((None, "m"), (None, "m"), (None, "m", None)), (None, None)),
+}
+
+
 def bitplane_matmul_pallas(exp: jnp.ndarray, sign: jnp.ndarray,
                            planes: jnp.ndarray, n_bits: int = 4,
                            block_m: int = 128, block_n: int = 128,
                            block_k: int = 128,
-                           interpret: bool | None = None) -> jnp.ndarray:
-    """exp/sign int8 (M, K), planes uint8 (8, K, N) -> int32 (M, N)."""
+                           interpret: bool | None = None,
+                           tp: str | None = None) -> jnp.ndarray:
+    """exp/sign int8 (M, K), planes uint8 (8, K, N) -> int32 (M, N).
+
+    ``tp`` names the weight's tensor-parallel layout (``"col"``: N on the
+    model axis, ``"row"``: K on it); under a multi-device mesh the kernel
+    runs per device on its own weight shard (``kernel_call``)."""
+    return _bitplane_matmul(exp, sign, planes, n_bits=n_bits,
+                            block_m=block_m, block_n=block_n,
+                            block_k=block_k, interpret=interpret,
+                            kmesh=kernel_mesh(), tp=tp)
+
+
+@functools.partial(jax.jit, static_argnames=("n_bits", "block_m", "block_n",
+                                             "block_k", "interpret", "kmesh",
+                                             "tp"))
+def _bitplane_matmul(exp, sign, planes, *, n_bits, block_m, block_n, block_k,
+                     interpret, kmesh, tp):
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    local = functools.partial(_bitplane_matmul_local, n_bits=n_bits,
+                              block_m=block_m, block_n=block_n,
+                              block_k=block_k, interpret=interpret)
+    in_layouts, out_layout = _TP_LAYOUTS[tp]
+    return kernel_call(local, kmesh, in_layouts, out_layout, exp, sign,
+                       planes, reduce_model=tp == "row")
+
+
+def _bitplane_matmul_local(exp, sign, planes, *, n_bits, block_m, block_n,
+                           block_k, interpret):
     m, k = exp.shape
     bits, _, n = planes.shape
 

@@ -44,7 +44,9 @@ def _log2quant_kernel(x_ref, exp_ref, sign_ref, *, n_bits: int):
     e = jnp.where(is_nonfinite & ~is_nan, emax, e)
 
     exp_ref[...] = e.astype(jnp.int8)
-    sign_ref[...] = jnp.where(x < 0, jnp.int8(-1), jnp.int8(1))
+    # select in int32, narrow at the store: Mosaic cannot relayout an i1
+    # mask into the int8 (32, 128) tiling an int8 select would need
+    sign_ref[...] = jnp.where(x < 0, -1, 1).astype(jnp.int8)
 
 
 def log2quant_specs(m: int, n: int, block_m: int, block_n: int):

@@ -57,11 +57,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-NEG_INF = -1e30
+from repro.core.logquant import pow2_exact
 
-# jax renamed TPUCompilerParams -> CompilerParams around 0.5; support both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or \
-    pltpu.TPUCompilerParams
+NEG_INF = -1e30
 
 # The long-context ragged decode tick used by BOTH the paged_attn kernel
 # microbench (benchmarks/kernel_bench.py) and the static kernel verifier
@@ -80,35 +78,38 @@ def paged_attn_specs(b: int, g: int, r: int, d: int, page_len: int,
     instantiation hands the same objects to the static verifier
     (``analysis.pallas_inspect``) — so the index maps the verifier proves
     in-bounds are the index maps the kernel ships, not a re-statement.
+
+    Every block's last two dims are the operand's full last two dims, the
+    form Mosaic accepts whatever G, R and D are: a K/V block is one whole
+    page ``(1, page_len, G, D)`` with all kv heads, q is ``(1, G, R, D)``,
+    and the outputs carry the split axis ahead of ``(G, R[, D])``.
     """
     assert nb % splits == 0, (nb, splits)
     bps = nb // splits
-    grid = (b, g, splits, bps)
+    grid = (b, splits, bps)
+    # the table walk: block index maps dereference the prefetched page
+    # table — page (tab[b, split*bps + j]) streams in, nothing else; the
+    # dense gather never happens
+    page = pl.BlockSpec((1, page_len, g, d),
+                        lambda bi, si, ji, tab, lens:
+                        (tab[bi, si * bps + ji], 0, 0, 0))
     in_specs = [
-        pl.BlockSpec((1, 1, r, d),
-                     lambda bi, gi, si, ji, tab, lens: (bi, gi, 0, 0)),
-        # the table walk: block index maps dereference the prefetched
-        # page table — page (tab[b, split*bps + j]) streams in, nothing
-        # else; the dense gather never happens
-        pl.BlockSpec((1, page_len, 1, d),
-                     lambda bi, gi, si, ji, tab, lens:
-                     (tab[bi, si * bps + ji], 0, gi, 0)),
-        pl.BlockSpec((1, page_len, 1, d),
-                     lambda bi, gi, si, ji, tab, lens:
-                     (tab[bi, si * bps + ji], 0, gi, 0)),
+        pl.BlockSpec((1, g, r, d),
+                     lambda bi, si, ji, tab, lens: (bi, 0, 0, 0)),
+        page,
+        page,
     ]
+    stat = pl.BlockSpec((1, 1, g, r),
+                        lambda bi, si, ji, tab, lens: (bi, si, 0, 0))
     out_specs = [
-        pl.BlockSpec((1, 1, 1, r, d),
-                     lambda bi, gi, si, ji, tab, lens:
-                     (bi, gi, si, 0, 0)),
-        pl.BlockSpec((1, 1, 1, r),
-                     lambda bi, gi, si, ji, tab, lens: (bi, gi, si, 0)),
-        pl.BlockSpec((1, 1, 1, r),
-                     lambda bi, gi, si, ji, tab, lens: (bi, gi, si, 0)),
+        pl.BlockSpec((1, 1, g, r, d),
+                     lambda bi, si, ji, tab, lens: (bi, si, 0, 0, 0)),
+        stat,
+        stat,
     ]
-    scratch_shapes = [pltpu.VMEM((r, 1), jnp.float32),
-                      pltpu.VMEM((r, 1), jnp.float32),
-                      pltpu.VMEM((r, d), jnp.float32)]
+    scratch_shapes = [pltpu.VMEM((g, r, 1), jnp.float32),
+                      pltpu.VMEM((g, r, 1), jnp.float32),
+                      pltpu.VMEM((g, r, d), jnp.float32)]
     return grid, in_specs, out_specs, scratch_shapes, bps
 
 
@@ -119,14 +120,17 @@ def paged_attn_quant_specs(b: int, g: int, r: int, d: int, page_len: int,
     Same grid/out/scratch; the K/V operands are packed log2 code pools
     (same (P, page_len, G, D) geometry, int8/int16 elements — the §IV
     traffic saving is the dtype shrink on exactly these block loads) plus
-    one (P, G) int32 scale-exponent pool each, block (1, 1) dereferenced
-    through the same page-table walk.
+    one int32 scale-exponent pool each, viewed as (P, 1, G) so that the
+    current page's row is a full-dim ``(1, 1, G)`` block; it goes to SMEM
+    through the same page-table walk (the kernel reads one scalar per
+    head).
     """
     grid, in_specs, out_specs, scratch_shapes, bps = paged_attn_specs(
         b, g, r, d, page_len, nb, splits)
     scale_spec = pl.BlockSpec(
-        (1, 1),
-        lambda bi, gi, si, ji, tab, lens: (tab[bi, si * bps + ji], gi))
+        (1, 1, g),
+        lambda bi, si, ji, tab, lens: (tab[bi, si * bps + ji], 0, 0),
+        memory_space=pltpu.SMEM)
     in_specs = [in_specs[0], in_specs[1], scale_spec, in_specs[2],
                 scale_spec]
     return grid, in_specs, out_specs, scratch_shapes, bps
@@ -139,58 +143,96 @@ def _dequant_block(codes, se, n_bits: int):
     large-but-finite values the position mask then erases — never Inf/NaN
     (mirrors ``core.logquant.dequantize_page_codes``)."""
     sentinel = -(1 << (n_bits - 1))
-    e = (codes >> 1).astype(jnp.int32)
-    ee = jnp.clip(e + se, -126, 127)
-    mag = jnp.exp2(ee.astype(jnp.float32))
-    val = jnp.where((codes & 1) != 0, -mag, mag)
+    c = codes.astype(jnp.int32)
+    e = c >> 1
+    mag = pow2_exact(jnp.clip(e + se, -126, 127))
+    val = jnp.where((c & 1) != 0, -mag, mag)
     return jnp.where(e == sentinel, 0.0, val)
 
 
-def _paged_attn_kernel(table_ref, lens_ref,      # scalar prefetch
-                       q_ref,                    # (1, 1, R, D)
-                       k_ref, v_ref,             # (1, page_len, 1, D)
-                       o_ref,                    # (1, 1, 1, R, D) f32
-                       m_ref, l_ref,             # (1, 1, 1, R) f32
-                       m_s, l_s, acc_s,          # VMEM scratch
-                       *, page_len: int, bps: int):
-    b = pl.program_id(0)
-    si = pl.program_id(2)
-    j = pl.program_id(3)
-
-    @pl.when(j == 0)
-    def _init():
-        m_s[...] = jnp.full_like(m_s, NEG_INF)
-        l_s[...] = jnp.zeros_like(l_s)
-        acc_s[...] = jnp.zeros_like(acc_s)
-
-    q = q_ref[0, 0]                               # (R, D)
-    k = k_ref[0, :, 0, :]                         # (page_len, D)
-    v = v_ref[0, :, 0, :]
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+def _online_softmax_step(q, k, v, g, pos, length, m_s, l_s, acc_s, *,
+                         zero_masked_p: bool):
+    """One page of kv head ``g``: fold ``(k, v)`` into the running
+    ``(m, l, acc)`` statistics of that head's R query rows.  f32 operands
+    multiply at full f32 precision (Mosaic's default passes bf16)."""
+    prec = (jax.lax.Precision.HIGHEST if q.dtype == jnp.float32
+            else jax.lax.Precision.DEFAULT)
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), precision=prec,
                             preferred_element_type=jnp.float32)
     s = s / jnp.sqrt(jnp.float32(q.shape[-1]))    # (R, page_len)
-    base = (si * bps + j) * page_len
-    pos = base + jax.lax.broadcasted_iota(jnp.int32, (1, page_len), 1)
-    s = jnp.where(pos < lens_ref[b], s, NEG_INF)
-
-    m_prev = m_s[...]                             # (R, 1)
+    valid = pos < length
+    s = jnp.where(valid, s, NEG_INF)
+    m_prev = m_s[g]                               # (R, 1)
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    if zero_masked_p:
+        # all-masked-so-far blocks keep m_new = NEG_INF, so masked p would
+        # be exp(0) = 1 against dequantized garbage of magnitude up to
+        # 2^127 — large enough for the junk accumulator to overflow to inf
+        # and turn the merge's zero weight into 0 * inf = NaN.  Zero the
+        # masked p explicitly: bitwise no-op for any block holding a valid
+        # token (there masked p already underflowed to exact 0.0)
+        p = jnp.where(valid, p, 0.0)
+    corr = jnp.exp(m_prev - m_new)
+    l_s[g] = l_s[g] * corr + jnp.sum(p, axis=-1, keepdims=True)
     # p is cast to the cache dtype before the PV product, mirroring the
     # dense path's `p.astype(q.dtype)` — keeps kernel-vs-dense drift to
     # the softmax reassociation alone
-    p = jnp.exp(s - m_new)
-    corr = jnp.exp(m_prev - m_new)
-    l_s[...] = l_s[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
     pv = jax.lax.dot_general(p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+                             precision=prec,
                              preferred_element_type=jnp.float32)
-    acc_s[...] = acc_s[...] * corr + pv
-    m_s[...] = m_new
+    acc_s[g] = acc_s[g] * corr + pv
+    m_s[g] = m_new
+
+
+def _init_stats(m_s, l_s, acc_s):
+    m_s[...] = jnp.full_like(m_s, NEG_INF)
+    l_s[...] = jnp.zeros_like(l_s)
+    acc_s[...] = jnp.zeros_like(acc_s)
+
+
+def _flush_stats(o_ref, m_ref, l_ref, m_s, l_s, acc_s):
+    o_ref[0, 0] = acc_s[...]
+    m_ref[0, 0] = m_s[..., 0]
+    l_ref[0, 0] = l_s[..., 0]
+
+
+def _page_positions(page_len: int, bps: int):
+    si = pl.program_id(1)
+    j = pl.program_id(2)
+    base = (si * bps + j) * page_len
+    return base + jax.lax.broadcasted_iota(jnp.int32, (1, page_len), 1)
+
+
+def _paged_attn_kernel(table_ref, lens_ref,      # scalar prefetch
+                       q_ref,                    # (1, G, R, D)
+                       k_ref, v_ref,             # (1, page_len, G, D)
+                       o_ref,                    # (1, 1, G, R, D) f32
+                       m_ref, l_ref,             # (1, 1, G, R) f32
+                       m_s, l_s, acc_s,          # VMEM scratch
+                       *, page_len: int, bps: int):
+    j = pl.program_id(2)
+
+    @pl.when(j == 0)
+    def _init():
+        _init_stats(m_s, l_s, acc_s)
+
+    pos = _page_positions(page_len, bps)
+    length = lens_ref[pl.program_id(0)]
+    for g in range(q_ref.shape[1]):               # static: G kv heads
+        _online_softmax_step(q_ref[0, g], k_ref[0, :, g, :],
+                             v_ref[0, :, g, :], g, pos, length,
+                             m_s, l_s, acc_s, zero_masked_p=False)
 
     @pl.when(j == bps - 1)
     def _flush():
-        o_ref[0, 0, 0] = acc_s[...]
-        m_ref[0, 0, 0] = m_s[..., 0]
-        l_ref[0, 0, 0] = l_s[..., 0]
+        _flush_stats(o_ref, m_ref, l_ref, m_s, l_s, acc_s)
+
+
+def _partials_shape(b: int, g: int, r: int, d: int, splits: int):
+    return [jax.ShapeDtypeStruct((b, splits, g, r, d), jnp.float32),
+            jax.ShapeDtypeStruct((b, splits, g, r), jnp.float32),
+            jax.ShapeDtypeStruct((b, splits, g, r), jnp.float32)]
 
 
 def paged_attention_kernel(qg: jnp.ndarray, k_pool: jnp.ndarray,
@@ -199,8 +241,8 @@ def paged_attention_kernel(qg: jnp.ndarray, k_pool: jnp.ndarray,
                            interpret: bool = False):
     """qg (B, G, R, D) grouped decode queries; k/v pool (P, page_len, G,
     D); page_table (B, NB) int32 with NB divisible by ``splits``; lengths
-    (B,) int32.  Returns partial ``(o, m, l)``: o (B, G, splits, R, D)
-    f32, m/l (B, G, splits, R) f32 — merge with
+    (B,) int32.  Returns partial ``(o, m, l)``: o (B, splits, G, R, D)
+    f32, m/l (B, splits, G, R) f32 — merge with
     :func:`ops.merge_split_softmax`."""
     b, g, r, d = qg.shape
     page_len = k_pool.shape[1]
@@ -219,72 +261,44 @@ def paged_attention_kernel(qg: jnp.ndarray, k_pool: jnp.ndarray,
     return pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((b, g, splits, r, d), jnp.float32),
-                   jax.ShapeDtypeStruct((b, g, splits, r), jnp.float32),
-                   jax.ShapeDtypeStruct((b, g, splits, r), jnp.float32)],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+        out_shape=_partials_shape(b, g, r, d, splits),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(page_table, lengths, qg, k_pool, v_pool)
 
 
 def _paged_attn_quant_kernel(table_ref, lens_ref,  # scalar prefetch
-                             q_ref,                # (1, 1, R, D)
-                             k_ref, ks_ref,        # (1, page_len, 1, D) codes
-                             v_ref, vs_ref,        # + (1, 1) int32 scale
+                             q_ref,                # (1, G, R, D)
+                             k_ref, ks_ref,        # (1, page_len, G, D) codes
+                             v_ref, vs_ref,        # + (1, 1, G) int32 scales (SMEM)
                              o_ref, m_ref, l_ref,
                              m_s, l_s, acc_s,
                              *, page_len: int, bps: int, n_bits: int):
     """Quantized-pool body: identical online-softmax walk to
     :func:`_paged_attn_kernel`, but each page block streams in as packed
-    log2 codes + one scale exponent and dequantizes in-register — the
-    wire format never round-trips through a dense pool.  The caller masks
-    to *full* pages only (``lengths`` floored to a page multiple); the
-    newest partial page merges as one extra dense-tail split outside
-    (``ops.paged_decode_attention_quant``)."""
-    b = pl.program_id(0)
-    si = pl.program_id(2)
-    j = pl.program_id(3)
+    log2 codes + one scale exponent per kv head and dequantizes
+    in-register — the wire format never round-trips through a dense pool.
+    The caller masks to *full* pages only (``lengths`` floored to a page
+    multiple); the newest partial page merges as one extra dense-tail
+    split outside (``ops.paged_decode_attention_quant``)."""
+    j = pl.program_id(2)
 
     @pl.when(j == 0)
     def _init():
-        m_s[...] = jnp.full_like(m_s, NEG_INF)
-        l_s[...] = jnp.zeros_like(l_s)
-        acc_s[...] = jnp.zeros_like(acc_s)
+        _init_stats(m_s, l_s, acc_s)
 
-    q = q_ref[0, 0]                               # (R, D)
-    k = _dequant_block(k_ref[0, :, 0, :], ks_ref[0, 0], n_bits)
-    v = _dequant_block(v_ref[0, :, 0, :], vs_ref[0, 0], n_bits)
-    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    s = s / jnp.sqrt(jnp.float32(q.shape[-1]))    # (R, page_len)
-    base = (si * bps + j) * page_len
-    pos = base + jax.lax.broadcasted_iota(jnp.int32, (1, page_len), 1)
-    s = jnp.where(pos < lens_ref[b], s, NEG_INF)
-
-    m_prev = m_s[...]                             # (R, 1)
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-    p = jnp.exp(s - m_new)
-    # all-masked-so-far blocks keep m_new = NEG_INF, so masked p would be
-    # exp(0) = 1 against dequantized garbage of magnitude up to 2^127 —
-    # large enough for the junk accumulator to overflow to inf and turn
-    # the merge's zero weight into 0 * inf = NaN.  Zero the masked p
-    # explicitly: bitwise no-op for any block holding a valid token
-    # (there masked p already underflowed to exact 0.0)
-    p = jnp.where(pos < lens_ref[b], p, 0.0)
-    corr = jnp.exp(m_prev - m_new)
-    l_s[...] = l_s[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
-    pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    acc_s[...] = acc_s[...] * corr + pv
-    m_s[...] = m_new
+    pos = _page_positions(page_len, bps)
+    length = lens_ref[pl.program_id(0)]
+    for g in range(q_ref.shape[1]):               # static: G kv heads
+        k = _dequant_block(k_ref[0, :, g, :], ks_ref[0, 0, g], n_bits)
+        v = _dequant_block(v_ref[0, :, g, :], vs_ref[0, 0, g], n_bits)
+        _online_softmax_step(q_ref[0, g], k, v, g, pos, length,
+                             m_s, l_s, acc_s, zero_masked_p=True)
 
     @pl.when(j == bps - 1)
     def _flush():
-        o_ref[0, 0, 0] = acc_s[...]
-        m_ref[0, 0, 0] = m_s[..., 0]
-        l_ref[0, 0, 0] = l_s[..., 0]
+        _flush_stats(o_ref, m_ref, l_ref, m_s, l_s, acc_s)
 
 
 def paged_attention_quant_kernel(qg: jnp.ndarray, k_codes: jnp.ndarray,
@@ -298,6 +312,8 @@ def paged_attention_quant_kernel(qg: jnp.ndarray, k_codes: jnp.ndarray,
     pages (the dense tail merges outside).  Returns partial ``(o, m, l)``
     like :func:`paged_attention_kernel`."""
     b, g, r, d = qg.shape
+    k_scale = k_scale.reshape(k_scale.shape[0], 1, g)
+    v_scale = v_scale.reshape(v_scale.shape[0], 1, g)
     page_len = k_codes.shape[1]
     nb = page_table.shape[1]
     grid, in_specs, out_specs, scratch_shapes, bps = paged_attn_quant_specs(
@@ -315,12 +331,9 @@ def paged_attention_quant_kernel(qg: jnp.ndarray, k_codes: jnp.ndarray,
     return pl.pallas_call(
         kern,
         grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((b, g, splits, r, d), jnp.float32),
-                   jax.ShapeDtypeStruct((b, g, splits, r), jnp.float32),
-                   jax.ShapeDtypeStruct((b, g, splits, r), jnp.float32)],
-        compiler_params=_CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
+        out_shape=_partials_shape(b, g, r, d, splits),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(page_table, lengths, qg, k_codes, k_scale, v_codes, v_scale)
 
@@ -395,10 +408,10 @@ def audit_specs():
             make_operand("v_pool", pool_shape, dtype, in_specs[2]),
         )
         outputs = (
-            make_operand("o", (b, g, splits, r, d), jnp.float32,
+            make_operand("o", (b, splits, g, r, d), jnp.float32,
                          out_specs[0]),
-            make_operand("m", (b, g, splits, r), jnp.float32, out_specs[1]),
-            make_operand("l", (b, g, splits, r), jnp.float32, out_specs[2]),
+            make_operand("m", (b, splits, g, r), jnp.float32, out_specs[1]),
+            make_operand("l", (b, splits, g, r), jnp.float32, out_specs[2]),
         )
         out.append(KernelInstantiation(
             kernel="paged_attention", case=name, grid=grid,
@@ -410,8 +423,8 @@ def audit_specs():
         ))
 
     # quantized-pool variants (ServeScheduler kv_quant=True): same table
-    # walk, but the K/V operands are packed log2 code pools + (P, G) scale
-    # pools — the audit's byte model makes the compressed-page traffic
+    # walk, but the K/V operands are packed log2 code pools + (P, 1, G)
+    # scale pools — the audit's byte model makes the compressed-page traffic
     # saving a gated number (page_read_saved_frac).  The kernel is masked
     # to full pages (lengths floored; the dense tail merges outside), but
     # the allocated tail page still streams, so liveness/table rules use
@@ -441,15 +454,17 @@ def audit_specs():
         inputs = (
             make_operand("q", (b, g, r, d), jnp.float32, in_specs[0]),
             make_operand("k_pool", pool_shape, ct, in_specs[1]),
-            make_operand("k_scale", (n_pages, g), jnp.int32, in_specs[2]),
+            make_operand("k_scale", (n_pages, 1, g), jnp.int32,
+                         in_specs[2]),
             make_operand("v_pool", pool_shape, ct, in_specs[3]),
-            make_operand("v_scale", (n_pages, g), jnp.int32, in_specs[4]),
+            make_operand("v_scale", (n_pages, 1, g), jnp.int32,
+                         in_specs[4]),
         )
         outputs = (
-            make_operand("o", (b, g, splits, r, d), jnp.float32,
+            make_operand("o", (b, splits, g, r, d), jnp.float32,
                          out_specs[0]),
-            make_operand("m", (b, g, splits, r), jnp.float32, out_specs[1]),
-            make_operand("l", (b, g, splits, r), jnp.float32, out_specs[2]),
+            make_operand("m", (b, splits, g, r), jnp.float32, out_specs[1]),
+            make_operand("l", (b, splits, g, r), jnp.float32, out_specs[2]),
         )
         out.append(KernelInstantiation(
             kernel="paged_attention", case=name, grid=grid,

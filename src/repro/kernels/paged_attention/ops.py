@@ -20,7 +20,15 @@ import numpy as np
 from repro.kernels.paged_attention.kernel import (NEG_INF,
                                                   paged_attention_kernel,
                                                   paged_attention_quant_kernel)
-from repro.models.sharding import shard
+from repro.models.sharding import kernel_call, kernel_mesh, shard
+
+# per-dim roles of the kernel operands under a mesh (models.sharding
+# .kernel_call): slots on the batch axes, kv heads on the model axis; the
+# pool is read whole on every device of a batch shard (page ids are global)
+_Q, _POOL, _ROW, _LEN = ("b", "m", None, None), (None, None, "m", None), \
+    ("b", None), ("b",)
+_PARTIALS = [("b", None, "m", None, None), ("b", None, "m", None),
+             ("b", None, "m", None)]
 
 
 def merge_split_softmax(m: jnp.ndarray, l: jnp.ndarray, acc: jnp.ndarray,
@@ -46,7 +54,6 @@ def merge_split_softmax(m: jnp.ndarray, l: jnp.ndarray, acc: jnp.ndarray,
     return num / jnp.maximum(l_tot, 1e-30)[..., None]
 
 
-@functools.partial(jax.jit, static_argnames=("splits", "interpret"))
 def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
                            v_pool: jnp.ndarray, page_table: jnp.ndarray,
                            lengths: jnp.ndarray, *, splits: int = 1,
@@ -58,8 +65,17 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     Returns (B, 1, H, D) in q's dtype — the drop-in replacement for
     ``_paged_gather`` + ``_decode_attention`` (token-equal on every tested
     seed/arch; logits agree to f32-ULP softmax reassociation, see
-    tests/test_paged_attention.py for the exact bar).
+    tests/test_paged_attention.py for the exact bar).  Under a
+    multi-device mesh the kernel runs per device (``kernel_call``).
     """
+    return _paged_decode_attention(q, k_pool, v_pool, page_table, lengths,
+                                   splits=splits, interpret=interpret,
+                                   kmesh=kernel_mesh())
+
+
+@functools.partial(jax.jit, static_argnames=("splits", "interpret", "kmesh"))
+def _paged_decode_attention(q, k_pool, v_pool, page_table, lengths, *,
+                            splits, interpret, kmesh):
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     b, _, h, d = q.shape
@@ -71,10 +87,12 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
         # so the kernel masks them to exact zeros like any junk tail
         page_table = jnp.pad(page_table, ((0, 0), (0, pad)))
     qg = q.reshape(b, 1, g, h // g, d)[:, 0]             # (B, G, R, D)
-    o, m, l = paged_attention_kernel(qg, k_pool, v_pool,
-                                     page_table.astype(jnp.int32),
-                                     lengths.astype(jnp.int32),
-                                     splits=splits, interpret=interpret)
+    kern = functools.partial(paged_attention_kernel, splits=splits,
+                             interpret=interpret)
+    o, m, l = kernel_call(kern, kmesh, (_Q, _POOL, _POOL, _ROW, _LEN),
+                          _PARTIALS, qg, k_pool, v_pool,
+                          page_table.astype(jnp.int32),
+                          lengths.astype(jnp.int32))
     # split-KV partial-reduce rule: the split axis may ride the model mesh
     # axis (models.sharding "kvsplit" kinds; launch.shardings
     # .split_kv_specs documents the layout) — each shard owns a contiguous
@@ -82,11 +100,10 @@ def paged_decode_attention(q: jnp.ndarray, k_pool: jnp.ndarray,
     o = shard(o, "kvsplit")
     m = shard(m, "kvsplit_stat")
     l = shard(l, "kvsplit_stat")
-    out = merge_split_softmax(m, l, o, axis=2)           # (B, G, R, D)
+    out = merge_split_softmax(m, l, o, axis=1)           # (B, G, R, D)
     return out.reshape(b, 1, h, d).astype(q.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("n_bits", "splits", "interpret"))
 def paged_decode_attention_quant(q: jnp.ndarray, k_codes: jnp.ndarray,
                                  k_scale: jnp.ndarray, v_codes: jnp.ndarray,
                                  v_scale: jnp.ndarray, k_tail: jnp.ndarray,
@@ -106,6 +123,17 @@ def paged_decode_attention_quant(q: jnp.ndarray, k_codes: jnp.ndarray,
     through the same :func:`merge_split_softmax`, so its tokens read
     exactly the bytes the dense pool would hold.
     """
+    return _paged_decode_attention_quant(
+        q, k_codes, k_scale, v_codes, v_scale, k_tail, v_tail, page_table,
+        lengths, n_bits=n_bits, splits=splits, interpret=interpret,
+        kmesh=kernel_mesh())
+
+
+@functools.partial(jax.jit, static_argnames=("n_bits", "splits", "interpret",
+                                             "kmesh"))
+def _paged_decode_attention_quant(q, k_codes, k_scale, v_codes, v_scale,
+                                  k_tail, v_tail, page_table, lengths, *,
+                                  n_bits, splits, interpret, kmesh):
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     b, _, h, d = q.shape
@@ -119,11 +147,13 @@ def paged_decode_attention_quant(q: jnp.ndarray, k_codes: jnp.ndarray,
     lengths = lengths.astype(jnp.int32)
     tb = jnp.maximum(lengths - 1, 0) // page_len         # tail-page block
     kern_lens = tb * page_len                            # full pages only
-    o, m, l = paged_attention_quant_kernel(qg, k_codes, k_scale, v_codes,
-                                           v_scale,
-                                           page_table.astype(jnp.int32),
-                                           kern_lens, n_bits=n_bits,
-                                           splits=splits, interpret=interpret)
+    kern = functools.partial(paged_attention_quant_kernel, n_bits=n_bits,
+                             splits=splits, interpret=interpret)
+    o, m, l = kernel_call(kern, kmesh,
+                          (_Q, _POOL, (None, "m"), _POOL, (None, "m"), _ROW,
+                           _LEN),
+                          _PARTIALS, qg, k_codes, k_scale, v_codes, v_scale,
+                          page_table.astype(jnp.int32), kern_lens)
     o = shard(o, "kvsplit")
     m = shard(m, "kvsplit_stat")
     l = shard(l, "kvsplit_stat")
@@ -136,8 +166,12 @@ def paged_decode_attention_quant(q: jnp.ndarray, k_codes: jnp.ndarray,
     kt = jnp.take_along_axis(k_tail, idx, axis=1)        # (B, pl, G, D)
     vt = jnp.take_along_axis(v_tail, idx, axis=1)
     pos = tb[:, None] * page_len + j[None, :]            # (B, pl) absolute
+    # the kernel's matmul precision (f32 queries at full f32 precision)
+    prec = (jax.lax.Precision.HIGHEST if q.dtype == jnp.float32
+            else jax.lax.Precision.DEFAULT)
     s_t = jnp.einsum("bgrd,bkgd->bgrk", qg.astype(jnp.float32),
-                     kt.astype(jnp.float32)) / jnp.sqrt(jnp.float32(d))
+                     kt.astype(jnp.float32),
+                     precision=prec) / jnp.sqrt(jnp.float32(d))
     s_t = jnp.where(pos[:, None, None, :] < lengths[:, None, None, None],
                     s_t, NEG_INF)
     m_t = jnp.max(s_t, axis=-1, keepdims=True)           # (B, G, R, 1)
@@ -146,14 +180,14 @@ def paged_decode_attention_quant(q: jnp.ndarray, k_codes: jnp.ndarray,
     p = jnp.exp(s_t - m_t)
     l_t = jnp.sum(p, axis=-1)                            # (B, G, R)
     acc_t = jnp.einsum("bgrk,bkgd->bgrd", p.astype(vt.dtype), vt,
-                       preferred_element_type=jnp.float32)
+                       precision=prec, preferred_element_type=jnp.float32)
 
     # append the tail as one extra split: kernel partials are UNNORMALIZED
     # accumulators, so the tail block composes through the same merge
-    o = jnp.concatenate([o, acc_t[:, :, None]], axis=2)
-    m = jnp.concatenate([m, m_t[..., 0][:, :, None]], axis=2)
-    l = jnp.concatenate([l, l_t[:, :, None]], axis=2)
-    out = merge_split_softmax(m, l, o, axis=2)           # (B, G, R, D)
+    o = jnp.concatenate([o, acc_t[:, None]], axis=1)
+    m = jnp.concatenate([m, m_t[..., 0][:, None]], axis=1)
+    l = jnp.concatenate([l, l_t[:, None]], axis=1)
+    out = merge_split_softmax(m, l, o, axis=1)           # (B, G, R, D)
     return out.reshape(b, 1, h, d).astype(q.dtype)
 
 

@@ -12,6 +12,22 @@ import re
 import warnings
 
 import jax
+from jax.sharding import AxisType
+
+
+def auto_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``.
+
+    The installed JAX defaults to ``Explicit`` axes, under which every
+    array carries its sharding in its type and GSPMD no longer propagates
+    shardings through the serve programs (a dynamic-update-slice of a
+    replicated cache with a data-sharded update then fails to trace).  The
+    repo's sharding rules are written for propagation, so every mesh it
+    builds goes through here.
+    """
+    kw = {} if devices is None else {"devices": devices}
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes), **kw)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -23,20 +39,21 @@ def make_production_mesh(*, multi_pod: bool = False):
     """
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return auto_mesh(shape, axes)
 
 
 def make_host_mesh(model_parallel: int = 1):
-    """``data x model`` mesh over whatever devices exist (CPU smoke runs).
+    """``data x model`` mesh over whatever devices exist.
 
-    A single-device process silently falls back to ``model_parallel=1``
-    (with a warning) so the same CLI invocation works on a laptop and under
-    ``--xla_force_host_platform_device_count``; any other indivisibility is
-    a real configuration error and raises (a ``ValueError``, not an assert —
-    asserts vanish under ``python -O``).
+    A single-CPU-device process falls back to ``model_parallel=1`` (with a
+    warning) so the same CLI invocation works on a laptop and under
+    ``--xla_force_host_platform_device_count``.  On an accelerator a
+    model-parallel request the devices cannot meet is a configuration
+    error, and like any other indivisibility it raises (a ``ValueError``,
+    not an assert — asserts vanish under ``python -O``).
     """
     n = len(jax.devices())
-    if model_parallel != 1 and n == 1:
+    if model_parallel != 1 and n == 1 and jax.default_backend() == "cpu":
         warnings.warn(f"make_host_mesh: only 1 device visible; falling back "
                       f"to model_parallel=1 (requested {model_parallel})",
                       stacklevel=2)
@@ -45,8 +62,8 @@ def make_host_mesh(model_parallel: int = 1):
         raise ValueError(
             f"make_host_mesh: model_parallel={model_parallel} must be >= 1 "
             f"and divide the visible device count ({n} devices)")
-    return jax.make_mesh((n // model_parallel, model_parallel),
-                         ("data", "model"))
+    return auto_mesh((n // model_parallel, model_parallel),
+                     ("data", "model"))
 
 
 _MESH_SPEC_RE = re.compile(r"^(\d+)x(\d+)$")
@@ -72,7 +89,7 @@ def make_serve_mesh(spec: str, model_parallel: int = 1):
                          f"{n} are visible (set XLA_FLAGS="
                          f"--xla_force_host_platform_device_count={d * t} "
                          f"for a CPU smoke run)")
-    return jax.make_mesh((d, t), ("data", "model"))
+    return auto_mesh((d, t), ("data", "model"))
 
 
 def batch_axes(mesh) -> tuple:

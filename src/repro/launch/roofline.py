@@ -1,14 +1,12 @@
 """Roofline analysis from dry-run artifacts (EXPERIMENTS.md §Roofline).
 
-Hardware model (TPU v5e, per chip):
-  peak bf16 compute : 197 TFLOP/s
-  HBM bandwidth     : 819 GB/s
-  ICI link bandwidth: ~50 GB/s per link
+Hardware model: :data:`PEAKS`, one row per ``jax.Device.device_kind``.
+A kind missing from the table is an error, never a default.
 
 Terms (seconds, per chip, per step):
-  compute    = HLO_FLOPs / 197e12
-  memory     = HLO_bytes / 819e9
-  collective = collective_bytes / 50e9
+  compute    = HLO_FLOPs / peak FLOP/s
+  memory     = HLO_bytes / HBM bytes/s
+  collective = collective_bytes / ICI bytes/s
 
 HLO_FLOPs / HLO_bytes / collective_bytes are per-chip values from the
 loop-aware analyzer (launch.hlo_analysis) over the SPMD-partitioned module;
@@ -27,11 +25,33 @@ import glob
 import json
 import os
 from dataclasses import dataclass
-from typing import Dict, List, Optional
+from typing import Dict, List, NamedTuple, Optional
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-ICI_BW = 50e9
+
+class Peaks(NamedTuple):
+    flops: float        # dense bf16 FLOP/s per chip
+    hbm_bw: float       # HBM bytes/s per chip
+    ici_bw: float       # chip-to-chip interconnect bytes/s per chip
+
+
+#: Published per-chip peaks keyed by ``device_kind``.  Source: Google Cloud
+#: documentation, "TPU v5e" (197 TFLOP/s bf16, 819 GB/s HBM, 1,600 Gbit/s
+#: ICI).
+PEAKS: Dict[str, Peaks] = {
+    "TPU v5 lite": Peaks(flops=197e12, hbm_bw=819e9, ici_bw=1600e9 / 8),
+}
+
+#: the chip the dry-run's placeholder meshes stand for (v5e pods)
+DRYRUN_DEVICE_KIND = "TPU v5 lite"
+
+
+def peaks_for(device_kind: str) -> Peaks:
+    """The peaks of one chip kind; an unknown kind raises."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device_kind "
+                       f"{device_kind!r}; known: {sorted(PEAKS)}") from None
 
 
 @dataclass
@@ -57,21 +77,23 @@ class RooflineRow:
         return (self.arch, self.shape, self.mesh)
 
 
-def analyze_cell(res: dict) -> Optional[RooflineRow]:
+def analyze_cell(res: dict,
+                 device_kind: str = DRYRUN_DEVICE_KIND) -> Optional[RooflineRow]:
     if res.get("skipped") or res.get("error"):
         return None
+    peak = peaks_for(device_kind)
     hlo = res["hlo"]
     chips = res["chips"]
-    compute_s = hlo["flops"] / PEAK_FLOPS
-    memory_s = hlo["traffic_bytes"] / HBM_BW
-    collective_s = hlo["collective_bytes_total"] / ICI_BW
+    compute_s = hlo["flops"] / peak.flops
+    memory_s = hlo["traffic_bytes"] / peak.hbm_bw
+    collective_s = hlo["collective_bytes_total"] / peak.ici_bw
 
     n = res["params_active"]
     d = res["tokens_per_step"]
     model_flops = (6.0 if res["step"] == "train" else 2.0) * n * d
     hlo_global = hlo["flops"] * chips
     bound = max(compute_s, memory_s, collective_s)
-    ideal = model_flops / chips / PEAK_FLOPS
+    ideal = model_flops / chips / peak.flops
     terms = {"compute": compute_s, "memory": memory_s,
              "collective": collective_s}
     dominant = max(terms, key=terms.get)
@@ -82,7 +104,7 @@ def analyze_cell(res: dict) -> Optional[RooflineRow]:
         dominant=dominant, model_flops=model_flops, hlo_flops=hlo_global,
         useful_ratio=model_flops / max(hlo_global, 1.0),
         useful_fraction=ideal / max(bound, 1e-30), bound_s=bound,
-        collective_breakdown={k: v / ICI_BW
+        collective_breakdown={k: v / peak.ici_bw
                               for k, v in hlo["collective_bytes"].items()},
     )
 

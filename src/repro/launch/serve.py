@@ -43,8 +43,10 @@ import jax.numpy as jnp
 
 from repro.configs import get_config, get_smoke
 from repro.launch.mesh import batch_axes, make_serve_mesh
-from repro.launch.shardings import cache_shardings, params_shardings
-from repro.models.model import init_caches, init_params
+from repro.launch.runtime import enable_compile_cache
+from repro.launch.shardings import (cache_shardings, init_params_sharded,
+                                    params_shardings)
+from repro.models.model import init_caches
 from repro.models.quantize import quantize_model_params
 from repro.models.sharding import mesh_axes
 from repro.serving.engine import make_decode_loop, make_prefill_step
@@ -101,49 +103,80 @@ def _load_serve_config(args):
         return ServeConfig.from_json(fh.read())
 
 
+def continuous_trace(cfg, config, *, requests: int, prompt_len: int,
+                     seed: int):
+    """The seeded request trace ``--continuous`` serves: ``requests``
+    prompts of 2..``prompt_len`` tokens (up to 3x ``prompt_len`` with
+    chunked prefill — past every bucket), and with a prefix cache a
+    shared-system-prompt workload (half the prompt is a common prefix) so
+    the radix tree has something to hit."""
+    import numpy as np
+
+    long_max = (3 * prompt_len) if config.chunked != "off" else prompt_len
+    rng = np.random.default_rng(seed)
+    prefix = (rng.integers(0, cfg.vocab_size, size=max(prompt_len // 2,
+                                                       config.page_len))
+              .astype(np.int32) if config.prefix_cache else None)
+    prompts = []
+    for _ in range(requests):
+        n = int(rng.integers(2, long_max + 1))
+        p = rng.integers(0, cfg.vocab_size, size=n).astype(np.int32)
+        if prefix is not None and rng.random() < 0.75:
+            p = np.concatenate([prefix, p])[:max(long_max, len(prefix) + 2)]
+        prompts.append(p)
+    return prompts
+
+
+def serve_continuous(cfg, params, config, prompts, *, max_new: int,
+                     eos_id=None, mesh=None, disaggregate: bool = False,
+                     keep_first_logits: bool = False):
+    """Serve ``prompts`` through the continuous-batching scheduler (or,
+    with ``disaggregate``, the prefill/decode router — identical tokens,
+    isolated decode ticks): submit everything, drain.  Returns
+    ``(results, scheduler, seconds)``; with ``keep_first_logits`` the
+    scheduler's ``first_logits`` holds each bucket-admitted request's
+    first-token logits."""
+    from repro.serving.router import Router
+    from repro.serving.scheduler import ServeScheduler
+
+    if disaggregate:
+        if not config.paged:
+            raise SystemExit("--disaggregate requires a paged config "
+                             "(add --paged, or paged=true in --config)")
+        sched = Router(cfg, params, config, mesh=mesh)
+    else:
+        sched = ServeScheduler(cfg, params, config, mesh=mesh)
+        if keep_first_logits:
+            sched.first_logits = {}
+    for p in prompts:
+        sched.submit(p, max_new=max_new, eos_id=eos_id)
+    t0 = time.perf_counter()
+    results = sched.run()
+    return results, sched, time.perf_counter() - t0
+
+
 def _serve_continuous(cfg, params, args, mesh):
-    """Queued-trace continuous batching: submit everything, drain, report
-    sustained tok/s + per-request latency + plane traffic.
+    """Queued-trace continuous batching (:func:`serve_continuous` over
+    :func:`continuous_trace`), reporting sustained tok/s + per-request
+    latency + plane traffic.
 
     With ``--chunked`` the trace includes LONG prompts (up to 3x
     ``--prompt-len``, past every prefill bucket) — rejected outright without
     chunking — ingested ``--chunk-len`` tokens per tick, interleaved with
     decode.  ``--disaggregate`` serves the same trace through the
     prefill/decode router (``serving/router.py``) instead of the combined
-    scheduler — identical tokens, isolated decode ticks."""
+    scheduler."""
     import numpy as np
-
-    from repro.serving.router import Router
-    from repro.serving.scheduler import ServeScheduler
 
     config = _load_serve_config(args)
     buckets = config.buckets
     chunked = config.chunked
-    long_max = ((3 * args.prompt_len) if chunked != "off"
-                else args.prompt_len)
     live_mesh = mesh if mesh is not None and mesh.size > 1 else None
-    if args.disaggregate:
-        if not config.paged:
-            raise SystemExit("--disaggregate requires a paged config "
-                             "(add --paged, or paged=true in --config)")
-        sched = Router(cfg, params, config, mesh=live_mesh)
-    else:
-        sched = ServeScheduler(cfg, params, config, mesh=live_mesh)
-    rng = np.random.default_rng(args.seed)
-    # with a prefix cache, draw a shared-system-prompt workload (half the
-    # prompt is a common prefix) so the radix tree has something to hit
-    prefix = (rng.integers(0, cfg.vocab_size, size=max(args.prompt_len // 2,
-                                                       config.page_len))
-              .astype(np.int32) if config.prefix_cache else None)
-    for _ in range(args.requests):
-        n = int(rng.integers(2, long_max + 1))
-        p = rng.integers(0, cfg.vocab_size, size=n).astype(np.int32)
-        if prefix is not None and rng.random() < 0.75:
-            p = np.concatenate([prefix, p])[:max(long_max, len(prefix) + 2)]
-        sched.submit(p, max_new=args.new_tokens, eos_id=args.eos_id)
-    t0 = time.perf_counter()
-    results = sched.run()
-    dt = time.perf_counter() - t0
+    prompts = continuous_trace(cfg, config, requests=args.requests,
+                               prompt_len=args.prompt_len, seed=args.seed)
+    results, sched, dt = serve_continuous(
+        cfg, params, config, prompts, max_new=args.new_tokens,
+        eos_id=args.eos_id, mesh=live_mesh, disaggregate=args.disaggregate)
     total = sum(len(r.tokens) for r in results)
     mesh_tag = ("1-device" if live_mesh is None else
                 "x".join(str(s) for s in live_mesh.devices.shape) + " mesh")
@@ -208,7 +241,9 @@ def _serve_continuous(cfg, params, args, mesh):
     print(f"sample request 0 ({r0.finish_reason}):", r0.tokens[:8])
 
 
-def main(argv=None):
+def build_parser() -> argparse.ArgumentParser:
+    """The serving CLI's flags (``main`` parses these; ``chip_smoke.py``
+    derives its serving configs from the same flags)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--smoke", action="store_true")
@@ -292,7 +327,11 @@ def main(argv=None):
                          "instead of the combined scheduler: identical "
                          "tokens, decode ticks isolated from prompt "
                          "ingestion (requires a paged config)")
-    args = ap.parse_args(argv)
+    return ap
+
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
 
     if args.dump_config is not None:
         text = _load_serve_config(args).to_json(indent=2)
@@ -303,6 +342,7 @@ def main(argv=None):
                 fh.write(text + "\n")
         return
 
+    enable_compile_cache()
     cfg = get_smoke(args.arch) if args.smoke else get_config(args.arch)
     if cfg.frontend == "audio_stub":
         raise SystemExit("use examples/serve_decode.py for the audio stub")
@@ -313,11 +353,12 @@ def main(argv=None):
     quant = args.quant_backend if args.quant else False
     with mesh, mesh_axes(batch=bax, model="model", seq_shard=False,
                          sizes=dict(mesh.shape), mesh=mesh):
-        params = init_params(jax.random.PRNGKey(args.seed), cfg)
+        params = init_params_sharded(jax.random.PRNGKey(args.seed), cfg,
+                                     mesh)
         if args.quant:
             params = quantize_model_params(cfg, params, pack=args.pack)
-        psh = params_shardings(mesh, params, fsdp=False)
-        params = jax.device_put(params, psh)
+            params = jax.device_put(
+                params, params_shardings(mesh, params, fsdp=False))
         if args.continuous:
             return _serve_continuous(cfg, params, args, mesh)
         caches = init_caches(cfg, args.batch, max_len, dtype=cfg.dtype)

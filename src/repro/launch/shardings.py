@@ -118,6 +118,22 @@ def params_shardings(mesh: Mesh, params_tree: Any, *, fsdp: bool = True,
     return jax.tree_util.tree_unflatten(treedef, out)
 
 
+def init_params_sharded(key, cfg, mesh: Mesh, *,
+                        model_axis: Optional[str] = "model") -> Any:
+    """Random weights built directly in their serving shardings (TP rules,
+    no FSDP): ``init_params`` jitted with ``out_shardings`` from its
+    ``eval_shape``, so no device ever holds an unsharded copy — a model
+    larger than one chip is built on a mesh that holds it."""
+    from repro.models.model import init_params
+
+    def init(k):
+        return init_params(k, cfg)
+
+    shapes = jax.eval_shape(init, key)
+    psh = params_shardings(mesh, shapes, fsdp=False, model_axis=model_axis)
+    return jax.jit(init, out_shardings=psh)(key)
+
+
 def opt_shardings(mesh: Mesh, opt_tree: Any, param_shardings_tree: Any,
                   extra_axes: tuple = ()) -> Any:
     """Moments follow their parameters; scalars replicate.
@@ -266,7 +282,7 @@ def split_kv_specs(mesh: Mesh, *, splits: int, batch: int,
 
     The paged-attention kernel partitions the KV page axis into ``splits``
     contiguous runs; each run emits partial online-softmax state — ``acc``
-    (B, G, split, R, D) plus the (m, l) statistics (B, G, split, R) — and
+    (B, split, G, R, D) plus the (m, l) statistics (B, split, G, R) — and
     the cross-split merge (``ops.merge_split_softmax``) is the only
     reduction that crosses runs.  Under a mesh the split axis rides the
     model axis (each model shard owns its page run and reads nothing
@@ -287,8 +303,8 @@ def split_kv_specs(mesh: Mesh, *, splits: int, batch: int,
         if nb > 1 and batch % nb == 0 else None
     s_entry = model_axis if msz > 1 and splits % msz == 0 else None
     return {
-        "partial": P(b_entry, None, s_entry, None, None),
-        "stat": P(b_entry, None, s_entry, None),
+        "partial": P(b_entry, s_entry, None, None, None),
+        "stat": P(b_entry, s_entry, None, None),
     }
 
 

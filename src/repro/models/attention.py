@@ -436,11 +436,11 @@ def attention(p, x: jnp.ndarray, positions: jnp.ndarray, cfg,
     h, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
 
     q = dense(p["wq"], x, p.get("bq"), p.get("wq_q") if quant else None,
-              ctx=quant)
+              ctx=quant, tp="col")
     k = dense(p["wk"], x, p.get("bk"), p.get("wk_q") if quant else None,
-              ctx=quant)
+              ctx=quant, tp="col")
     v = dense(p["wv"], x, p.get("bv"), p.get("wv_q") if quant else None,
-              ctx=quant)
+              ctx=quant, tp="col")
     q = q.reshape(b, s, h, hd)
     k = k.reshape(b, s, hkv, hd)
     v = v.reshape(b, s, hkv, hd)
@@ -623,5 +623,6 @@ def attention(p, x: jnp.ndarray, positions: jnp.ndarray, cfg,
         new_cache = KVCache(k=kc, v=vc, length=new_len)
 
     out = out.reshape(b, s, h * hd)
-    y = dense(p["wo"], out, quant=p.get("wo_q") if quant else None, ctx=quant)
+    y = dense(p["wo"], out, quant=p.get("wo_q") if quant else None, ctx=quant,
+              tp="row")
     return y, new_cache

@@ -70,7 +70,7 @@ def apply_rope(x: jnp.ndarray, positions: jnp.ndarray,
 
 def dense(w, x: jnp.ndarray, bias=None,
           quant: Optional[QuantizedLinearParams] = None,
-          ctx=None) -> jnp.ndarray:
+          ctx=None, tp: Optional[str] = None) -> jnp.ndarray:
     """Projection with optional QeiHaN path.
 
     ``w``: (K, N); ``x``: (..., K).  When ``quant`` is provided the GEMM runs
@@ -78,12 +78,15 @@ def dense(w, x: jnp.ndarray, bias=None,
     framework's first-class integration of the paper's technique).  ``ctx``
     (bool | str | QuantCtx) selects the backend ("xla" | "pallas") and
     optionally collects plane-traffic counts; see ``core.shiftadd.QuantCtx``.
+    ``tp`` is the weight's tensor-parallel layout (``"col"``: output dim on
+    the model axis, ``"row"``: input dim), as ``launch.shardings`` lays it
+    out; the bit-plane kernel needs it under a mesh.
     """
     if quant is not None:
         qc = as_quant_ctx(ctx) or QuantCtx()
         y = quantized_linear_apply(quant, x, n_bits=qc.n_bits,
                                    backend=qc.backend,
-                                   collect=qc.collect).astype(x.dtype)
+                                   collect=qc.collect, tp=tp).astype(x.dtype)
     else:
         y = jnp.matmul(x, w.astype(x.dtype))
     if bias is not None:
@@ -103,10 +106,11 @@ def swiglu(p, x: jnp.ndarray, quant=False) -> jnp.ndarray:
     QeiHaN path and is forwarded to ``dense`` as the backend/stats context).
     """
     g = dense(p["gate"], x, quant=p.get("gate_q") if quant else None,
-              ctx=quant)
-    u = dense(p["up"], x, quant=p.get("up_q") if quant else None, ctx=quant)
+              ctx=quant, tp="col")
+    u = dense(p["up"], x, quant=p.get("up_q") if quant else None, ctx=quant,
+              tp="col")
     h = jax.nn.silu(g.astype(jnp.float32)).astype(x.dtype) * u
     from repro.models.sharding import shard
     h = shard(h, "btf")
     return dense(p["down"], h, quant=p.get("down_q") if quant else None,
-                 ctx=quant)
+                 ctx=quant, tp="row")

@@ -98,7 +98,7 @@ def _route_local(p, x2d: jnp.ndarray, cfg) -> jnp.ndarray:
 def _route_ep_body(router, experts, x_loc, *, cfg, axis: str,
                    tokens_split: bool):
     """Runs per-chip inside shard_map.  x_loc: (b_loc, s_loc, d)."""
-    m = shctx.axis_size(axis)
+    m = jax.lax.axis_size(axis)
     col = jax.lax.axis_index(axis)
     b, s, d = x_loc.shape
     g = b * s
@@ -175,9 +175,9 @@ def _route_ep(p, x: jnp.ndarray, cfg, ctx) -> jnp.ndarray:
     e_spec = P(axis, None, None)
     body = functools.partial(_route_ep_body, cfg=cfg, axis=axis,
                              tokens_split=tokens_split)
-    fn = shctx.shard_map(body, mesh=mesh,
-                         in_specs=(P(), e_spec, x_spec),
-                         out_specs=x_spec, check_vma=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P(), e_spec, x_spec),
+                       out_specs=x_spec, check_vma=False)
     return fn(p["router"], p["experts"], x)
 
 

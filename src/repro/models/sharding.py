@@ -32,6 +32,7 @@ import threading
 from typing import Dict, Optional, Sequence
 
 import jax
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 _state = threading.local()
@@ -54,14 +55,14 @@ _KIND_LAYOUT = {
     # offset) indexing needs no sharded-axis reshape
     "pool": ("b", None, None, None),
     # split-KV flash-decode partials (kernels/paged_attention): acc
-    # (B, G, split, R, D) and the (m, l) statistics (B, G, split, R).  The
+    # (B, split, G, R, D) and the (m, l) statistics (B, split, G, R).  The
     # split axis rides the model axis — each model shard owns a contiguous
     # run of KV pages and its own partial softmax, and the cross-split
     # merge (ops.merge_split_softmax) is the only collective: a tiny
     # (B, G, R)-sized statistic reduce instead of an all-gathered cache
     # (launch.shardings.split_kv_specs is the jit-boundary image)
-    "kvsplit": ("b", None, "m", None, None),
-    "kvsplit_stat": ("b", None, "m", None),
+    "kvsplit": ("b", "m", None, None, None),
+    "kvsplit_stat": ("b", "m", None, None),
     # channels-REPLICATED (B, S, C): used with force=True to pin tensors
     # whose channel axis is about to be concat/split (the mamba conv window)
     "btc": ("b", None, None),
@@ -166,10 +167,7 @@ def shard(x: jax.Array, kind: str, force: bool = False) -> jax.Array:
     s = spec_for(kind, x.shape, force=force)
     if s is None:
         return x
-    try:
-        return jax.lax.with_sharding_constraint(x, s)
-    except (ValueError, TypeError):
-        return x
+    return _constrain(x, s)
 
 
 def replicate(x: jax.Array) -> jax.Array:
@@ -181,30 +179,70 @@ def replicate(x: jax.Array) -> jax.Array:
     ax = _axes()
     if ax is None or not ax["sizes"]:
         return x
-    try:
-        return jax.lax.with_sharding_constraint(
-            x, P(*([None] * getattr(x, "ndim", 0))))
-    except (ValueError, TypeError):
-        return x
+    return _constrain(x, P(*([None] * getattr(x, "ndim", 0))))
 
 
-def axis_size(name: str) -> int:
-    """Version-portable ``jax.lax.axis_size`` (absent before ~0.5): inside a
-    collective scope ``psum(1, name)`` constant-folds to the static size."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(name)
-    return jax.lax.psum(1, name)
+def _constrain(x: jax.Array, spec: P) -> jax.Array:
+    """``with_sharding_constraint`` on the bound mesh (a bare spec needs an
+    enclosing ``with mesh``).  A failing constraint raises: a hint that
+    silently does nothing would leave GSPMD free to pick a layout the
+    rules never meant."""
+    mesh = _axes()["mesh"]
+    if mesh is not None:
+        spec = jax.sharding.NamedSharding(mesh, spec)
+    return jax.lax.with_sharding_constraint(x, spec)
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None):
-    """Version-portable ``shard_map``: jax >= 0.6 exposes ``jax.shard_map``
-    (``check_vma``); older releases only have the experimental module
-    (``check_rep``).  All repo call sites go through this wrapper."""
-    if hasattr(jax, "shard_map"):
-        kw = {} if check_vma is None else {"check_vma": check_vma}
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    kw = {} if check_vma is None else {"check_rep": check_vma}
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kw)
+def kernel_mesh():
+    """``(mesh, batch axes, model axis)`` when the bound mesh spans more
+    than one device, else None.  Mosaic kernels are not partitioned by
+    GSPMD, so on such a mesh every Pallas kernel call runs per device
+    inside ``shard_map`` (:func:`kernel_call`).  Resolved outside any
+    ``jax.jit`` so the result can be a static argument."""
+    ax = _axes()
+    mesh = ax["mesh"] if ax is not None else None
+    if mesh is None or mesh.size == 1:
+        return None
+    return mesh, tuple(ax["batch"]), ax["model"]
+
+
+def kernel_call(fn, kmesh, in_layouts, out_layouts, *args,
+                reduce_model: bool = False):
+    """``fn(*args)``, per device under ``shard_map`` when ``kmesh`` (from
+    :func:`kernel_mesh`) is set.
+
+    Layouts give one role per dim — ``"b"`` the batch axes, ``"m"`` the
+    model axis, None replicated.  A role binds its axis only where the axis
+    size divides every input dim of that role; otherwise those dims stay
+    replicated (GSPMD gathers them around the call).  ``reduce_model``
+    sums the per-device results over the model axis (a GEMM whose
+    contraction dim is model-sharded).  ``out_layouts`` is one layout, or
+    a list of layouts for a kernel with several outputs."""
+    if kmesh is None:
+        return fn(*args)
+    mesh, batch, model = kmesh
+    sizes = {"b": int(np.prod([mesh.shape[a] for a in batch])),
+             "m": mesh.shape[model] if model else 1}
+    axis = {"b": (batch if len(batch) > 1 else batch[0]) if batch else None,
+            "m": model}
+    live = {r for r in ("b", "m") if sizes[r] > 1 and all(
+        dim % sizes[r] == 0
+        for lay, a in zip(in_layouts, args)
+        for role, dim in zip(lay, a.shape) if role == r)}
+
+    def spec(layout):
+        return P(*(axis[r] if r in live else None for r in layout))
+
+    multi = isinstance(out_layouts, list)
+
+    def local(*xs):
+        out = fn(*xs)
+        if reduce_model and "m" in live:
+            out = jax.lax.psum(out, model)
+        return tuple(out) if multi else out
+
+    out_specs = (tuple(spec(l) for l in out_layouts) if multi
+                 else spec(out_layouts))
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=tuple(spec(l) for l in in_layouts),
+                         out_specs=out_specs, check_vma=False)(*args)

@@ -153,7 +153,8 @@ def mamba2_block(p, x: jnp.ndarray, cfg,
     # forced GSPMD to reshard (full-tensor collective-permutes, §Perf log)
     if "in_proj" in p:                    # legacy fused layout
         zxbcdt = dense(p["in_proj"], x,
-                       quant=p.get("in_proj_q") if quant else None, ctx=quant)
+                       quant=p.get("in_proj_q") if quant else None, ctx=quant,
+                       tp="col")
         # pin channels replicated before the split (CPU-SPMD hazard:
         # split/concat must never run along a sharded axis)
         zxbcdt = shard(zxbcdt, "btc", force=True)
@@ -161,9 +162,9 @@ def mamba2_block(p, x: jnp.ndarray, cfg,
         xs_r, b, c = jnp.split(xbc, [d_inner, d_inner + n], axis=-1)
     else:
         z = dense(p["wz"], x, quant=p.get("wz_q") if quant else None,
-                  ctx=quant)
+                  ctx=quant, tp="col")
         xs_r = dense(p["wx"], x, quant=p.get("wx_q") if quant else None,
-                     ctx=quant)
+                     ctx=quant, tp="col")
         b = dense(p["wb"], x)
         c = dense(p["wc"], x)
         dt = dense(p["wdt"], x)
@@ -273,7 +274,7 @@ def mamba2_block(p, x: jnp.ndarray, cfg,
     y = rms_norm(y * jax.nn.silu(z.astype(jnp.float32)).astype(x.dtype),
                  p["norm"], cfg.norm_eps)
     out = dense(p["out_proj"], y, quant=p.get("out_proj_q") if quant else None,
-                ctx=quant)
+                ctx=quant, tp="row")
     if state is None:
         return out, None
     return out, new_state

@@ -131,9 +131,12 @@ class Router:
 def _worker_main(conn, role: str, spec: dict) -> None:
     """Worker process entry (spawn target — must be importable): rebuild
     the model from the arch name and the engine from ServeConfig JSON,
-    then serve the parent's RPC loop over the pipe."""
+    then serve the parent's RPC loop over the pipe.  Workers run on the
+    CPU backend: a TPU chip belongs to one process (DESIGN.md
+    §Disaggregated serving)."""
     import jax
 
+    jax.config.update("jax_platforms", "cpu")
     from repro.configs import get_config, get_smoke
     from repro.models import init_params
 
@@ -203,8 +206,16 @@ def run_disaggregated(trace, *, arch: str, config: ServeConfig,
     worker, ``PageSpan`` byte frames to the decode worker, and ticks the
     decode worker until every admitted request retires.  Also returns the
     decode worker's per-tick wall-clock (the isolation measurement).
+
+    Both workers run on the CPU backend, and a parent that holds a TPU is
+    refused: one process owns a chip.  On the chip, serve in one process
+    through :class:`Router`.
     """
     import multiprocessing as mp
+
+    from repro.launch.runtime import refuse_if_chip_held
+
+    refuse_if_chip_held("run_disaggregated")
 
     ctx = mp.get_context("spawn")
     spec = {"arch": arch, "smoke": smoke, "f32": f32, "seed": seed,

@@ -313,6 +313,11 @@ class ServeScheduler:
         # prefill-only ingestion never generates a token — the cut point
         # between the prefill and decode engines is post-chunk, pre-decode
         self._defer_decode = False
+        # set to {} to record each request's first-token logits (rid ->
+        # (V,) array) where they sit in the slot row before the tick that
+        # emits the token: bucketed admissions and deferred chunks.  A
+        # chunk that finishes and decodes inside one program is not seen.
+        self.first_logits: Optional[Dict[int, np.ndarray]] = None
 
         # the generate-program LRU serves the per-request parity / baseline
         # path (greedy_generate): size it so one program per (bucket x
@@ -961,6 +966,12 @@ class ServeScheduler:
              and (s.phase == "decode"
                   or (chunk_rows and finishing[i] and not defer[i]))
              for i, s in enumerate(self._slots)])
+
+        if self.first_logits is not None:
+            for i in np.flatnonzero(decode_mask):
+                s = self._slots[i]
+                if s.phase == "decode" and not s.tokens:
+                    self.first_logits[s.req.rid] = np.asarray(self._logits[i])
 
         pt = (jnp.asarray(self._table),) if self.paged else ()
         toks_h = fracs_h = cfrac_h = None

@@ -19,7 +19,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -36,8 +35,7 @@ def pipeline_forward(stage_fn: Callable, params_stacked, x_micro,
     ``x_micro``: (n_micro, mb, ...) — meaningful on stage 0.
     Returns (n_micro, mb, ...) outputs — meaningful on the last stage.
     """
-    from repro.models.sharding import axis_size
-    n_stages = axis_size(axis)
+    n_stages = jax.lax.axis_size(axis)
     stage = jax.lax.axis_index(axis)
     n_micro = x_micro.shape[0]
     total_ticks = n_micro + n_stages - 1
@@ -69,9 +67,9 @@ def make_pipelined_fn(mesh: Mesh, stage_fn: Callable, *, axis: str = "pipe"):
     """Wrap ``pipeline_forward`` in shard_map on ``mesh`` (params stacked on
     the stage axis; activations enter on stage 0 and leave on the last)."""
     fn = functools.partial(pipeline_forward, stage_fn, axis=axis)
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )

@@ -36,7 +36,7 @@ class TestJaxprRules:
         j = jax.make_jaxpr(bad)(jnp.zeros((4,)))
         f = jaxpr_rules.rule_no_host_callback(j, "v", "p")
         assert f and f[0].rule == "no-host-callback"
-        assert "debug_callback" in f[0].detail
+        assert "debug_print" in f[0].detail
 
     def test_callback_inside_scan_flagged(self):
         # the rule must see through lax.scan's body jaxpr
@@ -57,8 +57,7 @@ class TestJaxprRules:
         assert not jaxpr_rules.rule_no_integer_upcast(j, "v", "p")
 
     def test_f64_flagged(self):
-        from jax.experimental import enable_x64
-        with enable_x64():
+        with jax.enable_x64(True):
             j = jax.make_jaxpr(
                 lambda x: x.astype(jnp.float64) + 1.0)(
                     jnp.zeros((3,), jnp.float32))
@@ -67,8 +66,7 @@ class TestJaxprRules:
         assert "float64" in f[0].detail
 
     def test_i64_flagged(self):
-        from jax.experimental import enable_x64
-        with enable_x64():
+        with jax.enable_x64(True):
             j = jax.make_jaxpr(
                 lambda x: x.astype(jnp.int64) * 2)(
                     jnp.zeros((3,), jnp.int32))
@@ -140,7 +138,8 @@ class TestShardedRearrange:
     def mesh(self):
         # degenerate 1x1 mesh: PartitionSpec bookkeeping is identical to a
         # real mesh, so the rule is testable on one device
-        return jax.make_mesh((1, 1), ("data", "model"))
+        from repro.launch.mesh import auto_mesh
+        return auto_mesh((1, 1), ("data", "model"))
 
     def test_model_sharded_concat_flagged(self, mesh):
         s = NamedSharding(mesh, P(None, "model"))

@@ -200,7 +200,7 @@ class TestCodeWidths:
     @needs_hypothesis
     def test_pack_roundtrip_property(self):
         @settings(max_examples=150, deadline=None)
-        @given(n_bits=st.sampled_from(WIDTHS),
+        @given(n_bits=st.sampled_from(self.WIDTHS),
                xs=st.lists(finite_f32, min_size=1, max_size=64))
         def run(n_bits, xs):
             _check_pack_roundtrip_width(xs, n_bits)

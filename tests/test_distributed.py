@@ -27,6 +27,7 @@ class TestShardedTraining:
     def test_train_step_dp_tp(self):
         out = run_py("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import auto_mesh
         from jax.sharding import NamedSharding, PartitionSpec as P
         from repro.configs import get_smoke
         from repro.models import init_params
@@ -36,7 +37,7 @@ class TestShardedTraining:
         from repro.launch.shardings import (batch_shardings, opt_shardings,
                                             params_shardings)
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = auto_mesh((4, 2), ("data", "model"))
         cfg = get_smoke("qwen3_32b")
         with mesh, mesh_axes(batch=("data",), model="model", seq_shard=True,
                              sizes=dict(mesh.shape), mesh=mesh):
@@ -62,6 +63,7 @@ class TestShardedTraining:
     def test_moe_ep_shardmap_matches_local(self):
         out = run_py("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import auto_mesh
         from repro.configs import get_smoke
         from repro.models import init_params, forward
         from repro.models.sharding import mesh_axes
@@ -73,7 +75,7 @@ class TestShardedTraining:
                                     cfg.vocab_size)
         ref, _ = forward(cfg, params, tokens=tokens)       # local path
 
-        mesh = jax.make_mesh((2, 4), ("data", "model"))    # E=4 experts / 4
+        mesh = auto_mesh((2, 4), ("data", "model"))    # E=4 experts / 4
         with mesh, mesh_axes(batch=("data",), model="model", seq_shard=True,
                              sizes=dict(mesh.shape), mesh=mesh):
             got, _ = jax.jit(lambda p, t: forward(cfg, p, tokens=t))(
@@ -87,10 +89,11 @@ class TestShardedTraining:
     def test_pipeline_forward_matches_sequential(self):
         out = run_py("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import auto_mesh
         from repro.train.pipeline import make_pipelined_fn
 
         n_stages, n_micro, mb, d = 8, 4, 2, 16
-        mesh = jax.make_mesh((n_stages,), ("pipe",))
+        mesh = auto_mesh((n_stages,), ("pipe",))
         key = jax.random.PRNGKey(0)
         ws = jax.random.normal(key, (n_stages, d, d)) * 0.3
 
@@ -112,20 +115,20 @@ class TestShardedTraining:
     def test_compressed_psum_matches_mean(self):
         out = run_py("""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import auto_mesh
         from functools import partial
         from jax.sharding import PartitionSpec as P
         from repro.optim.compress import compressed_psum_mean
-        from repro.models.sharding import shard_map
 
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = auto_mesh((8,), ("data",))
         g = jax.random.normal(jax.random.PRNGKey(0), (8, 64))
 
         def body(gl):
             red, err = compressed_psum_mean({"g": gl}, "data")
             return red["g"], err["g"]
 
-        fn = shard_map(body, mesh=mesh, in_specs=P("data"),
-                       out_specs=(P(), P("data")), check_vma=False)
+        fn = jax.shard_map(body, mesh=mesh, in_specs=P("data"),
+                           out_specs=(P(), P("data")), check_vma=False)
         red, err = fn(g)
         true_mean = jnp.mean(g.reshape(8, 1, 64), axis=0)
         rel = float(jnp.max(jnp.abs(red[0] - true_mean)) /
@@ -143,17 +146,18 @@ class TestElasticRestart:
         # save on a (4,2) mesh, restore on (2,4) — elastic restart
         out = run_py(f"""
         import jax, jax.numpy as jnp, numpy as np
+        from repro.launch.mesh import auto_mesh
         from repro.checkpoint import CheckpointManager
         from repro.launch.shardings import params_shardings
 
         tree = {{"w": jnp.arange(64, dtype=jnp.float32).reshape(8, 8)}}
-        mesh1 = jax.make_mesh((4, 2), ("data", "model"))
+        mesh1 = auto_mesh((4, 2), ("data", "model"))
         sh1 = params_shardings(mesh1, tree, fsdp_threshold=1)
         t1 = jax.device_put(tree, sh1)
         mgr = CheckpointManager(r"{tmp_path}", keep=2)
         mgr.save(1, t1)
 
-        mesh2 = jax.make_mesh((2, 4), ("data", "model"))
+        mesh2 = auto_mesh((2, 4), ("data", "model"))
         sh2 = params_shardings(mesh2, tree, fsdp_threshold=1)
         t2 = mgr.restore(1, tree, sh2)
         np.testing.assert_array_equal(np.asarray(t2["w"]), np.asarray(tree["w"]))
