@@ -237,8 +237,28 @@ def _serve_continuous(cfg, params, args, mesh):
               f"{int(st['lookup_hits'])}/{int(st['lookups'])} lookups hit; "
               f"pages {int(st['pages_in_use'])} in use / "
               f"{int(st['pages_free'])} free)")
+    scheds = ([("prefill", stats_sched), ("decode", sched.decode.scheduler)]
+              if args.disaggregate else [("scheduler", sched)])
+    for tag, s in scheds:
+        print(f"[serve] {tag} counters: {_counter_summary(s.counters())}")
     r0 = results[0]
     print(f"sample request 0 ({r0.finish_reason}):", r0.tokens[:8])
+
+
+def _counter_summary(c) -> str:
+    """One line from ``ServeScheduler.counters()``: admission stalls, the
+    share of chunk-slab rows that carried a prompt token, and the share of
+    reserved KV page-ticks that held no token yet."""
+    out = f"{c['admit_stalls']} admission stalls in {c['ticks']} ticks"
+    if c["chunk_slab_rows"]:
+        live = c["chunk_tokens"] / c["chunk_slab_rows"]
+        out += (f"; chunk slab rows {100 * live:.1f}% live "
+                f"({c['chunk_tokens']}/{c['chunk_slab_rows']})")
+    if c["kv_page_ticks_reserved"]:
+        idle = 1 - c["kv_page_ticks_written"] / c["kv_page_ticks_reserved"]
+        out += (f"; KV pages {100 * idle:.1f}% reserved but unwritten "
+                f"(page-ticks, pool of {c['kv_pages_capacity']})")
+    return out
 
 
 def build_parser() -> argparse.ArgumentParser:

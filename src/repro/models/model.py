@@ -330,60 +330,71 @@ def _apply_block(cfg: ModelConfig, kind: str, p: Params, x, positions,
     base = kind.split("_")[0]
     is_moe = kind.endswith("_moe")
     x = shard(x, "btd")                     # keep the scan carry SP-sharded
-    h = rms_norm(x, p["ln1"], cfg.norm_eps)
-    if base == "attn":
-        if cache is None:
-            kv = None
-        elif page_table is not None and "k_codes" in cache:
-            # log2-quantized page pool: packed codes + per-page scales +
-            # dense tail ring (models/attention.py quantized paths)
-            kv = QuantPagedKVCache(
-                k_codes=cache["k_codes"], v_codes=cache["v_codes"],
-                k_scale=cache["k_scale"], v_scale=cache["v_scale"],
-                k_tail=cache["k_tail"], v_tail=cache["v_tail"],
-                page_table=page_table, length=cache_len)
-        elif page_table is not None:
-            # paged slot pool: this layer's KV is a page pool indexed by
-            # the shared host-built page table (models/attention.py)
-            kv = PagedKVCache(k=cache["k"], v=cache["v"],
-                              page_table=page_table, length=cache_len)
+    # the mixer ("attn" | "mamba") and the MLP each run under a named
+    # scope: HLO metadata only, so a profile splits a layer's device
+    # time between them (DESIGN.md §Observability)
+    with jax.named_scope(base):
+        h = rms_norm(x, p["ln1"], cfg.norm_eps)
+        if base == "attn":
+            if cache is None:
+                kv = None
+            elif page_table is not None and "k_codes" in cache:
+                # log2-quantized page pool: packed codes + per-page
+                # scales + dense tail ring (models/attention.py quantized
+                # paths)
+                kv = QuantPagedKVCache(
+                    k_codes=cache["k_codes"], v_codes=cache["v_codes"],
+                    k_scale=cache["k_scale"], v_scale=cache["v_scale"],
+                    k_tail=cache["k_tail"], v_tail=cache["v_tail"],
+                    page_table=page_table, length=cache_len)
+            elif page_table is not None:
+                # paged slot pool: this layer's KV is a page pool indexed
+                # by the shared host-built page table (models/attention.py)
+                kv = PagedKVCache(k=cache["k"], v=cache["v"],
+                                  page_table=page_table, length=cache_len)
+            else:
+                kv = KVCache(k=cache["k"], v=cache["v"], length=cache_len)
+            out, new_kv = attention(p, h, positions, cfg, cache=kv,
+                                    quant=quant, chunk_valid=chunk_valid)
+            if new_kv is None:
+                new_cache = None
+            elif isinstance(new_kv, QuantPagedKVCache):
+                new_cache = {"k_codes": new_kv.k_codes,
+                             "v_codes": new_kv.v_codes,
+                             "k_scale": new_kv.k_scale,
+                             "v_scale": new_kv.v_scale,
+                             "k_tail": new_kv.k_tail,
+                             "v_tail": new_kv.v_tail}
+            else:
+                new_cache = {"k": new_kv.k, "v": new_kv.v}
         else:
-            kv = KVCache(k=cache["k"], v=cache["v"], length=cache_len)
-        out, new_kv = attention(p, h, positions, cfg, cache=kv, quant=quant,
-                                chunk_valid=chunk_valid)
-        if new_kv is None:
-            new_cache = None
-        elif isinstance(new_kv, QuantPagedKVCache):
-            new_cache = {"k_codes": new_kv.k_codes, "v_codes": new_kv.v_codes,
-                         "k_scale": new_kv.k_scale, "v_scale": new_kv.v_scale,
-                         "k_tail": new_kv.k_tail, "v_tail": new_kv.v_tail}
-        else:
-            new_cache = {"k": new_kv.k, "v": new_kv.v}
-    else:
-        st = None if cache is None else ssd_lib.SSMState(
-            ssm=cache["ssm"], conv=cache["conv"])
-        # a chunk's per-row valid count doubles as the SSM pad mask: pad
-        # tokens get dt = 0 (state passes through untouched) and the rolling
-        # conv window re-anchors at the real-token boundary — the same
-        # masking bucketed prefill uses, applied mid-prompt
-        out, new_st = ssd_lib.mamba2_block(
-            p, h, cfg, state=st, quant=quant,
-            valid_len=chunk_valid if chunk_valid is not None else valid_len)
-        new_cache = None if new_st is None else {
-            "ssm": new_st.ssm, "conv": new_st.conv}
-    # hint the projection output to the residual sharding *before* the add so
-    # GSPMD emits reduce-scatter (SP) rather than all-reduce + slice
-    out = shard(out, "btd")
-    x = x + out
+            st = None if cache is None else ssd_lib.SSMState(
+                ssm=cache["ssm"], conv=cache["conv"])
+            # a chunk's per-row valid count doubles as the SSM pad mask:
+            # pad tokens get dt = 0 (state passes through untouched) and the
+            # rolling conv window re-anchors at the real-token boundary —
+            # the same masking bucketed prefill uses, applied mid-prompt
+            out, new_st = ssd_lib.mamba2_block(
+                p, h, cfg, state=st, quant=quant,
+                valid_len=(chunk_valid if chunk_valid is not None
+                           else valid_len))
+            new_cache = None if new_st is None else {
+                "ssm": new_st.ssm, "conv": new_st.conv}
+        # hint the projection output to the residual sharding *before* the
+        # add so GSPMD emits reduce-scatter (SP) rather than all-reduce +
+        # slice
+        out = shard(out, "btd")
+        x = x + out
     if "mlp" in p:
-        h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
-        if is_moe:
-            y = moe_lib.moe_apply(p["mlp"], h2, cfg, quant=quant)
-        else:
-            y = swiglu(p["mlp"], h2, quant=quant)
-        y = shard(y, "btd")
-        x = x + y
-        x = shard(x, "btd")
+        with jax.named_scope("mlp"):
+            h2 = rms_norm(x, p["ln2"], cfg.norm_eps)
+            if is_moe:
+                y = moe_lib.moe_apply(p["mlp"], h2, cfg, quant=quant)
+            else:
+                y = swiglu(p["mlp"], h2, quant=quant)
+            y = shard(y, "btd")
+            x = x + y
+            x = shard(x, "btd")
     return x, new_cache
 
 
@@ -504,10 +515,12 @@ def forward(cfg: ModelConfig, params: Params, *,
                       "length": cache_len + (s if chunk_valid is None
                                              else chunk_valid)}
 
-    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
-    head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    logits = jnp.matmul(x, head.astype(x.dtype))
-    logits = shard(logits, "btv")
+    with jax.named_scope("head"):
+        x = rms_norm(x, params["final_norm"], cfg.norm_eps)
+        head = (params["embed"].T if cfg.tie_embeddings
+                else params["lm_head"])
+        logits = jnp.matmul(x, head.astype(x.dtype))
+        logits = shard(logits, "btv")
     if not return_stats:
         return logits, new_caches
     tile_f, tile_t, el_f, el_t = (jnp.sum(t) for t in traffic)

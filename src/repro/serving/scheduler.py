@@ -96,6 +96,11 @@ from repro.serving.kvpool import (TRASH_PAGE, PagePool, RadixCache,
 _LEGACY_KWARGS = frozenset(
     f.name for f in dataclasses.fields(ServeConfig)) - {"mesh_spec"}
 
+# the scheduler's host spans (``serve.*``): events on the profiler's host
+# plane while a ``jax.profiler`` trace runs, about a microsecond each when
+# none does (DESIGN.md §Observability)
+_span = jax.profiler.TraceAnnotation
+
 
 def bucket_for(length: int, buckets: Sequence[int]) -> int:
     """Smallest configured bucket that holds ``length`` real tokens."""
@@ -165,6 +170,7 @@ class _Slot:
     # admitted with, and the SSM/conv state snapshot at the cacheable
     # prompt boundary (hybrid models, captured opportunistically)
     pages: List[int] = dataclasses.field(default_factory=list)
+    shared_pages: int = 0               # pages[:shared_pages] alias a hit
     hit_len: int = 0
     snapshot: Optional[tuple] = None
 
@@ -365,6 +371,10 @@ class ServeScheduler:
         self._results: Dict[int, RequestResult] = {}
         self._next_rid = 0
         self._tick_count = 0
+        # cumulative scheduler counters (read through counters())
+        self._counters = {"chunk_tokens": 0, "chunk_slab_rows": 0,
+                          "admit_stalls": 0, "kv_page_ticks_reserved": 0,
+                          "kv_page_ticks_written": 0}
 
         # sharding specs: pool batch on `data`, kv-seq/ssm-heads on `model`,
         # per-slot (B,) lengths on `data`; params get the TP rules (incl.
@@ -552,9 +562,13 @@ class ServeScheduler:
                     frac = jnp.zeros((2,), jnp.float32)
                 return (lg, cs), (tok, frac)
 
-            (lg, cs), (toks, fracs) = jax.lax.scan(
-                body, (logits, pool), None, length=tick_steps)
-            return lg, cs, jnp.swapaxes(toks, 0, 1), fracs
+            # the decode scan and the chunk forward below run under named
+            # scopes ("decode", "chunk"): a profile of the mixed program,
+            # which holds both, splits its device time between them
+            with jax.named_scope("decode"):
+                (lg, cs), (toks, fracs) = jax.lax.scan(
+                    body, (logits, pool), None, length=tick_steps)
+                return lg, cs, jnp.swapaxes(toks, 0, 1), fracs
 
         if self.paged:
             def tick_paged(params, pool, logits, active, page_table):
@@ -579,16 +593,18 @@ class ServeScheduler:
             def chunk_body(params, pool, logits, tokens, valid, fresh,
                            finishing, page_table=None):
                 extra = (page_table,) if self.paged else ()
-                out = chunk_step(params, pool, logits, tokens, valid, fresh,
-                                 finishing, *extra)
-                if with_stats:
-                    lg, cs, stats = out
-                    cfrac = jnp.stack([stats["plane_traffic_fraction"],
-                                       stats["element_traffic_fraction"]])
-                else:
-                    lg, cs = out
-                    cfrac = jnp.zeros((2,), jnp.float32)
-                return lg, cs, cfrac
+                with jax.named_scope("chunk"):
+                    out = chunk_step(params, pool, logits, tokens, valid,
+                                     fresh, finishing, *extra)
+                    if with_stats:
+                        lg, cs, stats = out
+                        cfrac = jnp.stack(
+                            [stats["plane_traffic_fraction"],
+                             stats["element_traffic_fraction"]])
+                    else:
+                        lg, cs = out
+                        cfrac = jnp.zeros((2,), jnp.float32)
+                    return lg, cs, cfrac
 
             def mixed_tick(params, pool, logits, active, tokens, valid,
                            fresh, finishing, page_table=None):
@@ -900,6 +916,45 @@ class ServeScheduler:
             self._radix.lookups = self._radix.hits = 0
             self._radix.tokens_hit = 0
 
+    def counters(self) -> Dict[str, int]:
+        """The scheduler's counters, read on the host with no device sync.
+
+        Cumulative since construction: ``ticks`` (ticks that ran),
+        ``chunk_tokens`` (real prompt tokens fed through the chunk slab),
+        ``chunk_slab_rows`` (``max_slots x chunk_len`` for each tick that
+        ran a chunk), ``admit_stalls`` (ticks whose admission stopped on a
+        request the page pool could not yet hold), and
+        ``kv_page_ticks_reserved`` / ``kv_page_ticks_written`` (the two
+        page gauges below summed over the ticks, each read as the tick
+        ends).  Gauges of the paged KV pool, zero without one:
+        ``kv_pages_capacity`` (usable pages), ``kv_pages_reserved`` (pages
+        held by live slots) and ``kv_pages_written`` (those holding at
+        least one written token of a live slot); a prefix page that
+        several slots share counts once."""
+        out = dict(self._counters, ticks=self._tick_count,
+                   kv_pages_capacity=0, kv_pages_reserved=0,
+                   kv_pages_written=0)
+        if self.paged:
+            reserved, written = self._kv_pages()
+            out.update(kv_pages_capacity=self._pages.capacity,
+                       kv_pages_reserved=reserved, kv_pages_written=written)
+        return out
+
+    def _kv_pages(self):
+        """(reserved, written) pages of the live slots, O(max_slots) plus
+        the shared prefix pages."""
+        shared, reserved, written = set(), 0, 0
+        for s in self._slots:
+            if s is None:
+                continue
+            n = s.shared_pages       # whole prefix pages, all written
+            shared.update(s.pages[:n])
+            cached = (s.prefill_pos if s.phase == "prefill"
+                      else s.req.prompt.size + len(s.tokens))
+            reserved += len(s.pages) - n
+            written += blocks_for_tokens(cached, self.page_len) - n
+        return reserved + len(shared), written + len(shared)
+
     def step_tick(self) -> bool:
         """Admit into every free slot, feed one prompt chunk to every
         prefilling slot, run one fused multi-step decode tick for every
@@ -914,6 +969,13 @@ class ServeScheduler:
         instead (reject / truncate / raise) — exhaustion never crashes a
         live serve loop.
         """
+        with _span("serve.tick"):
+            return self._step_tick()
+
+    def _step_tick(self) -> bool:
+        # host phases, each a profiler span (DESIGN.md §Observability):
+        # serve.admit per request, then serve.slab, serve.launch,
+        # serve.sync and serve.bookkeep
         stalled = False
         for i in range(self.max_slots):
             if stalled:
@@ -923,6 +985,7 @@ class ServeScheduler:
                 st = self._admit(i, req)
                 if st == "wait":
                     self._queue.appendleft(req)
+                    self._counters["admit_stalls"] += 1
                     stalled = True
                     break
                 # "ok" fills the slot (loop exits); "drop" recorded a
@@ -930,121 +993,133 @@ class ServeScheduler:
         if not self._active.any():
             return False
 
-        # ---- build this tick's chunk slab (chunked admissions only) -------
-        chunk_rows = [i for i, s in enumerate(self._slots)
-                      if s is not None and s.phase == "prefill"]
-        valid = np.zeros((self.max_slots,), np.int32)
-        defer = np.zeros((self.max_slots,), bool)
-        if chunk_rows:
-            tokens = np.zeros((self.max_slots, self.chunk_len), np.int32)
-            fresh = np.zeros((self.max_slots,), bool)
-            finishing = np.zeros((self.max_slots,), bool)
+        with _span("serve.slab"):
+            # ---- build this tick's chunk slab (chunked admissions only) ---
+            chunk_rows = [i for i, s in enumerate(self._slots)
+                          if s is not None and s.phase == "prefill"]
+            valid = np.zeros((self.max_slots,), np.int32)
+            defer = np.zeros((self.max_slots,), bool)
+            if chunk_rows:
+                tokens = np.zeros((self.max_slots, self.chunk_len), np.int32)
+                fresh = np.zeros((self.max_slots,), bool)
+                finishing = np.zeros((self.max_slots,), bool)
+                for i in chunk_rows:
+                    s = self._slots[i]
+                    take = min(self.chunk_len,
+                               s.req.prompt.size - s.prefill_pos)
+                    tokens[i, :take] = s.req.prompt[s.prefill_pos:
+                                                    s.prefill_pos + take]
+                    valid[i] = take
+                    fresh[i] = s.prefill_pos == 0 and s.hit_len == 0
+                    finishing[i] = s.prefill_pos + take >= s.req.prompt.size
+                    # hybrid-model snapshot capture needs the post-prompt
+                    # SSM state BEFORE any decode step touches it: when the
+                    # final chunk lands exactly on the cacheable
+                    # (page-aligned) prompt boundary, hold the row out of
+                    # this tick's decode scan and capture after the tick —
+                    # it starts decoding next tick with identical tokens
+                    # (the logits/state don't change)
+                    defer[i] = finishing[i] and (
+                        self._defer_decode
+                        or (self._wants_snapshot(s)
+                            and s.prefill_pos + take
+                            == self._cacheable_len(s.req.prompt.size)))
+                self._counters["chunk_tokens"] += int(valid.sum())
+                self._counters["chunk_slab_rows"] += tokens.size
+            # a slot whose LAST chunk lands this tick decodes in the same
+            # tick: the chunk phase writes its first-token logits before
+            # the scan runs
+            decode_mask = np.array(
+                [s is not None and not s.done
+                 and (s.phase == "decode"
+                      or (chunk_rows and finishing[i] and not defer[i]))
+                 for i, s in enumerate(self._slots)])
+
+            if self.first_logits is not None:
+                for i in np.flatnonzero(decode_mask):
+                    s = self._slots[i]
+                    if s.phase == "decode" and not s.tokens:
+                        self.first_logits[s.req.rid] = np.asarray(
+                            self._logits[i])
+
+            pt = (jnp.asarray(self._table),) if self.paged else ()
+            slab = (tuple(jnp.asarray(a) for a in
+                          (tokens, valid, fresh, finishing))
+                    if chunk_rows else ())
+            mask = jnp.asarray(decode_mask)
+
+        with _span("serve.launch"):
+            toks = fracs = cfrac = None
+            if chunk_rows and decode_mask.any():
+                lg, pool, toks, fracs, cfrac = self._mixed(
+                    self.params, self._pool, self._logits, mask, *slab, *pt)
+            elif chunk_rows:
+                lg, pool, cfrac = self._chunk(
+                    self.params, self._pool, self._logits, *slab, *pt)
+            else:
+                lg, pool, toks, fracs = self._tick(
+                    self.params, self._pool, self._logits, mask, *pt)
+            self._logits, self._pool = lg, pool
+
+        with _span("serve.sync"):
+            # the host waits for the device here
+            toks_h = None if toks is None else np.asarray(toks)
+            fracs_h = None if fracs is None else np.asarray(fracs)
+            cfrac_h = None if cfrac is None else np.asarray(cfrac)
+
+        with _span("serve.bookkeep"):
+            now = time.perf_counter()
+
+            # ---- chunk-phase bookkeeping ----------------------------------
             for i in chunk_rows:
                 s = self._slots[i]
-                take = min(self.chunk_len,
-                           s.req.prompt.size - s.prefill_pos)
-                tokens[i, :take] = s.req.prompt[s.prefill_pos:
-                                                s.prefill_pos + take]
-                valid[i] = take
-                fresh[i] = s.prefill_pos == 0 and s.hit_len == 0
-                finishing[i] = s.prefill_pos + take >= s.req.prompt.size
-                # hybrid-model snapshot capture needs the post-prompt SSM
-                # state BEFORE any decode step touches it: when the final
-                # chunk lands exactly on the cacheable (page-aligned) prompt
-                # boundary, hold the row out of this tick's decode scan and
-                # capture after the tick — it starts decoding next tick with
-                # identical tokens (the logits/state don't change)
-                defer[i] = finishing[i] and (
-                    self._defer_decode
-                    or (self._wants_snapshot(s)
-                        and s.prefill_pos + take
-                        == self._cacheable_len(s.req.prompt.size)))
-        # a slot whose LAST chunk lands this tick decodes in the same tick:
-        # the chunk phase writes its first-token logits before the scan runs
-        decode_mask = np.array(
-            [s is not None and not s.done
-             and (s.phase == "decode"
-                  or (chunk_rows and finishing[i] and not defer[i]))
-             for i, s in enumerate(self._slots)])
+                s.prefill_pos += int(valid[i])
+                if finishing[i]:
+                    s.phase = "decode"
+                if (self._wants_snapshot(s) and s.prefill_pos
+                        == self._cacheable_len(s.req.prompt.size)):
+                    # post-tick state is exactly the state at prefill_pos:
+                    # the row was held out of (or not yet in) the decode
+                    # scan, and inactive rows' recurrent state is masked
+                    # frozen
+                    s.snapshot = self._snap(self._pool,
+                                            jnp.asarray(i, jnp.int32))
+                if self.with_stats and cfrac_h is not None:
+                    # the chunk forward's batch-aggregate traffic,
+                    # attributed to the requests that prefilled this tick
+                    # (decode steps are attributed below, exactly as before)
+                    s.frac_sums[0] += float(cfrac_h[0])
+                    s.frac_sums[1] += float(cfrac_h[1])
+                    s.frac_steps += 1
 
-        if self.first_logits is not None:
-            for i in np.flatnonzero(decode_mask):
-                s = self._slots[i]
-                if s.phase == "decode" and not s.tokens:
-                    self.first_logits[s.req.rid] = np.asarray(self._logits[i])
+            # ---- decode-phase bookkeeping ---------------------------------
+            if toks_h is not None:
+                for t in range(self.tick_steps):
+                    for i, slot in enumerate(self._slots):
+                        if slot is None or slot.done or not decode_mask[i]:
+                            continue
+                        tok = int(toks_h[i, t])
+                        if not slot.tokens:
+                            slot.first_token_time = now
+                        slot.tokens.append(tok)
+                        if self.with_stats:
+                            slot.frac_sums[0] += float(fracs_h[t, 0])
+                            slot.frac_sums[1] += float(fracs_h[t, 1])
+                            slot.frac_steps += 1
+                        if slot.req.eos_id is not None \
+                                and tok == slot.req.eos_id:
+                            slot.done, slot.finish_reason = True, "eos"
+                        elif len(slot.tokens) >= slot.req.max_new:
+                            slot.done, slot.finish_reason = True, "length"
 
-        pt = (jnp.asarray(self._table),) if self.paged else ()
-        toks_h = fracs_h = cfrac_h = None
-        if chunk_rows and decode_mask.any():
-            lg, pool, toks, fracs, cfrac = self._mixed(
-                self.params, self._pool, self._logits,
-                jnp.asarray(decode_mask), jnp.asarray(tokens),
-                jnp.asarray(valid), jnp.asarray(fresh),
-                jnp.asarray(finishing), *pt)
-            self._logits, self._pool = lg, pool
-            toks_h, fracs_h = np.asarray(toks), np.asarray(fracs)
-            cfrac_h = np.asarray(cfrac)
-        elif chunk_rows:
-            lg, pool, cfrac = self._chunk(
-                self.params, self._pool, self._logits, jnp.asarray(tokens),
-                jnp.asarray(valid), jnp.asarray(fresh),
-                jnp.asarray(finishing), *pt)
-            self._logits, self._pool = lg, pool
-            cfrac_h = np.asarray(cfrac)
-        else:
-            lg, pool, toks, fracs = self._tick(
-                self.params, self._pool, self._logits,
-                jnp.asarray(decode_mask), *pt)
-            self._logits, self._pool = lg, pool
-            toks_h, fracs_h = np.asarray(toks), np.asarray(fracs)
-
-        now = time.perf_counter()
-
-        # ---- chunk-phase bookkeeping --------------------------------------
-        for i in chunk_rows:
-            s = self._slots[i]
-            s.prefill_pos += int(valid[i])
-            if finishing[i]:
-                s.phase = "decode"
-            if (self._wants_snapshot(s) and s.prefill_pos
-                    == self._cacheable_len(s.req.prompt.size)):
-                # post-tick state is exactly the state at prefill_pos: the
-                # row was held out of (or not yet in) the decode scan, and
-                # inactive rows' recurrent state is masked frozen
-                s.snapshot = self._snap(self._pool,
-                                        jnp.asarray(i, jnp.int32))
-            if self.with_stats and cfrac_h is not None:
-                # the chunk forward's batch-aggregate traffic, attributed to
-                # the requests that prefilled this tick (decode steps are
-                # attributed below, exactly as before)
-                s.frac_sums[0] += float(cfrac_h[0])
-                s.frac_sums[1] += float(cfrac_h[1])
-                s.frac_steps += 1
-
-        # ---- decode-phase bookkeeping -------------------------------------
-        if toks_h is not None:
-            for t in range(self.tick_steps):
-                for i, slot in enumerate(self._slots):
-                    if slot is None or slot.done or not decode_mask[i]:
-                        continue
-                    tok = int(toks_h[i, t])
-                    if not slot.tokens:
-                        slot.first_token_time = now
-                    slot.tokens.append(tok)
-                    if self.with_stats:
-                        slot.frac_sums[0] += float(fracs_h[t, 0])
-                        slot.frac_sums[1] += float(fracs_h[t, 1])
-                        slot.frac_steps += 1
-                    if slot.req.eos_id is not None \
-                            and tok == slot.req.eos_id:
-                        slot.done, slot.finish_reason = True, "eos"
-                    elif len(slot.tokens) >= slot.req.max_new:
-                        slot.done, slot.finish_reason = True, "length"
-
-        self._tick_count += 1
-        for i, slot in enumerate(self._slots):
-            if slot is not None and slot.done:
-                self._retire(i)
+            self._tick_count += 1
+            for i, slot in enumerate(self._slots):
+                if slot is not None and slot.done:
+                    self._retire(i)
+            if self.paged:
+                reserved, written = self._kv_pages()
+                self._counters["kv_page_ticks_reserved"] += reserved
+                self._counters["kv_page_ticks_written"] += written
         return True
 
     def run(self, max_ticks: Optional[int] = None) -> List[RequestResult]:
@@ -1096,20 +1171,25 @@ class ServeScheduler:
         """Fill ``slot_idx`` with ``req``; returns ``"ok"`` (admitted),
         ``"wait"`` (paged pool exhausted while other requests are in
         flight — retry next tick), or ``"drop"`` (request rejected with a
-        per-request error result)."""
-        if self.paged:
-            return self._admit_paged(slot_idx, req)
-        length = int(req.prompt.size)
-        if self._uses_chunks(length):
-            # chunked ingestion: no prefill here — step_tick feeds the
-            # prompt chunk-by-chunk into the pool, interleaved with decode
-            self._active[slot_idx] = True
-            self._slots[slot_idx] = _Slot(req=req,
-                                          admitted_tick=self._tick_count,
-                                          phase="prefill")
+        per-request error result).  Runs inside a ``serve.admit`` span that
+        carries the request's ``rid`` and its ``path`` (``bucket``,
+        ``chunk`` or ``hit``)."""
+        with _span("serve.admit", rid=req.rid) as span:
+            if self.paged:
+                return self._admit_paged(slot_idx, req, span)
+            length = int(req.prompt.size)
+            if self._uses_chunks(length):
+                # chunked ingestion: no prefill here — step_tick feeds the
+                # prompt chunk-by-chunk into the pool, interleaved with
+                # decode
+                span.set_metadata(path="chunk")
+                self._active[slot_idx] = True
+                self._slots[slot_idx] = _Slot(
+                    req=req, admitted_tick=self._tick_count, phase="prefill")
+                return "ok"
+            span.set_metadata(path="bucket")
+            self._admit_bucketed(slot_idx, req)
             return "ok"
-        self._admit_bucketed(slot_idx, req)
-        return "ok"
 
     def _admit_bucketed(self, slot_idx: int, req: Request,
                         page_args: tuple = ()) -> None:
@@ -1127,7 +1207,7 @@ class ServeScheduler:
         self._slots[slot_idx] = _Slot(req=req,
                                       admitted_tick=self._tick_count)
 
-    def _admit_paged(self, slot_idx: int, req: Request,
+    def _admit_paged(self, slot_idx: int, req: Request, span,
                      retrying: bool = False) -> str:
         prompt = req.prompt
         length = int(prompt.size)
@@ -1140,6 +1220,8 @@ class ServeScheduler:
                                      need_snapshot=self._has_ssm,
                                      min_hit=self.min_prefix_hit,
                                      allow_partial=not self._has_ssm)
+        span.set_metadata(path="hit" if hit is not None else
+                          "chunk" if self._uses_chunks(length) else "bucket")
         shared = list(hit.pages) if hit is not None else []
         # hold references on every page the hit aliases (shared blocks AND
         # the COW source) BEFORE allocating: allocation may evict radix
@@ -1176,7 +1258,8 @@ class ServeScheduler:
                           self.max_len - req.max_new)
                 if fit >= 1:
                     cut = dataclasses.replace(req, prompt=prompt[-fit:])
-                    return self._admit_paged(slot_idx, cut, retrying=True)
+                    return self._admit_paged(slot_idx, cut, span,
+                                             retrying=True)
             now = time.perf_counter()
             self._results[req.rid] = RequestResult(
                 rid=req.rid, prompt_len=length, tokens=[],
@@ -1236,7 +1319,7 @@ class ServeScheduler:
                 # before any decode tick advances it
                 slot.snapshot = self._snap(self._pool,
                                            jnp.asarray(slot_idx, jnp.int32))
-        slot.pages = pages
+        slot.pages, slot.shared_pages = pages, len(shared)
         self.prefix_stats["pages_held"] += len(pages)
         self.prefix_stats["admitted"] += 1
         self._active[slot_idx] = True

@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.configs import get_config
+from repro.configs import get_config, get_smoke
 from repro.kernels.bitplane_matmul.ops import bitplane_matmul_pallas
 from repro.kernels.log2quant.ops import log2_quantize_pallas
 from repro.kernels.paged_attention.ops import (paged_decode_attention,
@@ -98,3 +98,35 @@ def test_bitplane_matmul(spec):
 def test_log2quant(spec):
     fn = functools.partial(log2_quantize_pallas, interpret=False)
     _assert_mosaic(fn, spec((B, CFG.d_model), jnp.float32))
+
+
+def test_tick_program_keeps_kernel_name(spec, monkeypatch):
+    """The paged-attention kernel compiled into the serve tick program
+    keeps the instruction name a profile finds it by,
+    ``_paged_decode_attention``, and sits under the tick's ``decode`` and
+    the model's ``attn`` scopes."""
+    import re
+
+    from repro.models import init_params
+    from repro.serving import ServeConfig, ServeScheduler
+
+    # the kernel takes Mosaic over interpret mode by the default backend
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = get_smoke("smollm_135m").replace(n_layers=1)
+    sched = ServeScheduler(cfg, init_params(jax.random.PRNGKey(0), cfg),
+                           ServeConfig(max_slots=B, max_len=NB * PAGE_LEN,
+                                       buckets=(64,), paged=True,
+                                       page_len=PAGE_LEN, tick_steps=2,
+                                       attn_kernel="pallas"))
+    fn, args = sched.audit_programs()["tick"]
+    args = jax.tree.map(lambda a: spec(a.shape, a.dtype), args)
+    text = fn.lower(*args).compile().as_text()
+    assert text.startswith("HloModule jit_tick_paged,")
+    calls = re.findall(r'%(\S+) = .* custom-call\(.*'
+                       r'custom_call_target="tpu_custom_call".*'
+                       r'op_name="([^"]+)"', text)
+    assert calls
+    for name, op in calls:
+        assert name.startswith("_paged_decode_attention")
+        assert op.startswith("jit(tick_paged)/decode/")
+        assert "/attn/" in op
