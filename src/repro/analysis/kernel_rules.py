@@ -190,19 +190,36 @@ def rule_unmasked_tail(inst: KernelInstantiation) -> List[Finding]:
 # ---------------------------------------------------------------------------
 
 
+def _pool_of(name: str) -> str:
+    """The pool a page operand reads: the float kernel passes each pool
+    ``ppb`` times, as ``k_pool.<i>`` / ``v_pool.<i>``."""
+    return name.split(".")[0]
+
+
+def _by_pool(counts: Dict[str, float]) -> Dict[str, float]:
+    out: Dict[str, float] = {}
+    for name, v in counts.items():
+        out[_pool_of(name)] = out.get(_pool_of(name), 0) + v
+    return out
+
+
 def _traffic_paged(inst: KernelInstantiation) -> Tuple[Dict, List[Finding]]:
     meta = inst.meta
     table = np.asarray(meta["table"])
     lens = np.asarray(meta["lengths"])
     page_len, bps = int(meta["page_len"]), int(meta["bps"])
+    ppb = int(meta.get("ppb", 1))
 
     def live(name: str, gidx: Tuple[int, ...]) -> bool:
-        if name not in ("k_pool", "v_pool", "k_scale", "v_scale"):
+        if _pool_of(name) not in ("k_pool", "v_pool", "k_scale", "v_scale"):
             return True
         bi, si, ji = gidx
-        return si * bps + ji < -(-int(lens[bi]) // page_len)
+        i = int(name.split(".")[1]) if "." in name else 0
+        return (si * bps + ji) * ppb + i < -(-int(lens[bi]) // page_len)
 
-    tr = block_traffic(inst, live=live)
+    # useful traffic (pages holding valid tokens), per pool
+    live_tr = block_traffic(inst, live=live)
+    tr = {k: _by_pool(v) for k, v in live_tr.items()}
 
     # the static gather fraction: pages the table walk touches, per slot
     # (one fetch per page: a block carries every kv head of its page)
@@ -235,11 +252,29 @@ def _traffic_paged(inst: KernelInstantiation) -> Tuple[Dict, List[Finding]]:
             )
         )
 
+    # every copy the walk issues, dead blocks included: a block past a
+    # slot's length must repeat the pages before it, so beyond the live
+    # pages a slot costs at most ppb more
+    issued = block_traffic(inst)
+    issued_pages = _by_pool(issued["fetches"])["k_pool"]
+    cap = static_touched + ppb * table.shape[0]
+    if issued_pages > cap:
+        findings.append(
+            _finding(
+                "kernel-traffic-model",
+                inst,
+                f"the walk copies {issued_pages} K pages, more than the "
+                f"{static_touched} live pages plus {ppb} per slot ({cap}) — "
+                f"dead blocks are being fetched",
+            )
+        )
+
     record = {
         "bytes_read": int(sum(tr["read"].values())),
         "bytes_written": int(sum(tr["written"].values())),
         "fetches": {k: int(v) for k, v in sorted(tr["fetches"].items())},
         "gather_saved_frac": saved_frac,
+        "bytes_issued": int(sum(issued["read"].values())),
     }
     if "k_scale" in tr["fetches"]:
         # quantized pool: page bytes actually streamed (packed codes +

@@ -334,7 +334,10 @@ def extract_pallas_calls(jaxpr, _mult: int = 1) -> List[PallasCallSite]:
             # debug info ("<name> at <file>:<line>")
             src = eqn.params["jaxpr"].debug_info.func_src_info
             name = src.split(" ")[0]
-            avals = [v.aval for v in eqn.invars]
+            # an operand passed more than once (the paged kernel's pool,
+            # once per page of a block) is one buffer: count it once
+            invars = list({id(v): v for v in eqn.invars}.values())
+            avals = [v.aval for v in invars]
             out.append(
                 PallasCallSite(
                     kernel_name=name,

@@ -7,14 +7,43 @@ actually needs.  The serving-side image of that is this kernel: instead of
 gathering ``pool[table]`` into a dense padded ``(B, max_len, G, D)`` view
 every decode tick (reading ALL allocated pages of every slot, valid or
 not), the BlockSpec index maps below dereference the scalar-prefetched
-page table themselves — block ``j`` of slot ``b`` loads pool page
-``table[b, j]`` directly, so only resident pages ever stream into VMEM and
-nothing is ever re-laid-out densely.
+page table themselves — a grid step of slot ``b`` loads the pool pages
+its columns of ``table[b]`` name directly, so only resident pages ever
+stream into VMEM and nothing is ever re-laid-out densely.
 
-Per (slot, kv-head, split) the kernel walks that split's pages in order
-with the standard online-softmax recurrence (running max ``m``, running
-normalizer ``l``, rescaled accumulator ``acc`` — the same f32 statistics
-``models.attention.flash_attention`` carries over KV chunks):
+**Block walk.** A grid step covers ``ppb`` consecutive table columns
+(:func:`block_pages`: about 128 tokens, 8 pages of 16).  The K and V
+pools are each passed ``ppb`` times, one one-page BlockSpec per column,
+so the step's pages arrive through Pallas's own pipelined copies.  Page
+operand ``i`` of block ``j`` of slot ``b`` names
+
+    table[b, min(j * ppb + i, own_i(b))],
+    last(b)  = max(ceil(length_b / page_len) - 1, 0)
+    own_i(b) = last(b) - (last(b) - i) mod ppb   (i <= last(b), else last(b))
+
+``own_i`` is the last live column that operand ``i`` walks, so past the
+slot's length every operand names the page it named the step before.  A
+block wholly past the length then repeats the previous step's pages, the
+pipeline issues no copy, and the body's ``pl.when(block_start <
+length)`` skips its compute; in the last live block the operands past
+the length hold earlier live pages, which the position mask erases.
+Dead table columns are never read at all, and a slot's copies beyond
+its live pages are the ``ppb - 1 - last`` repeats of a slot shorter than
+one block.  The clamp is applied once a call, in XLA, as a ``(B, NB)``
+walk table (:func:`clamped_walk`) that the index maps read as they
+would the page table: evaluating it inside 16 index maps cost about
+0.7 us a grid step on a v5e.  ``ppb`` depends only on ``page_len`` and
+the table width, never on ``splits``: block boundaries are then the
+same for every split count, and a split that holds no valid token stays
+bitwise absent from the merge.
+
+Per (slot, split) the kernel walks that split's blocks in order with
+the standard online-softmax recurrence (running max ``m``, running
+normalizer ``l``, rescaled accumulator ``acc`` — the same f32
+statistics ``models.attention.flash_attention`` carries over KV chunks).
+Per kv head a block's pages join into one ``(ppb * page_len, D)`` K and
+V operand, so one score matmul, one softmax update and one PV matmul
+cover the block:
 
     s_j  = (q @ k_j^T) / sqrt(D),  masked to  pos < length  with the
            finite NEG_INF = -1e30 (never -inf: all-masked blocks then
@@ -24,15 +53,15 @@ normalizer ``l``, rescaled accumulator ``acc`` — the same f32 statistics
     p    = exp(s_j - m');  corr = exp(m - m')
     l    = l * corr + sum_k p;   acc = acc * corr + p @ v_j
 
-**Split-KV ("flash-decode", SNIPPETS.md flashdecode idiom)**: the page
-axis is additionally partitioned into ``splits`` contiguous runs mapped to
-a parallel grid axis; each run emits partial ``(acc, m, l)`` and the tiny
-cross-split merge happens outside the kernel
+**Split-KV ("flash-decode", SNIPPETS.md flashdecode idiom)**: the block
+axis is additionally partitioned into ``splits`` contiguous runs mapped
+to a parallel grid axis; each run emits partial ``(acc, m, l)`` and the
+tiny cross-split merge happens outside the kernel
 (``ops.merge_split_softmax``).  A split that holds no valid token
-accumulates uniform junk at ``m = NEG_INF``; the merge weights it by
-``exp(NEG_INF - m_real) == 0.0`` exactly (f32 underflow), so junk splits
-— and trash-page contents in general — are *bitwise* absent from the
-output.  Under a mesh the split axis can ride the ``model`` axis
+computes nothing and emits ``m = NEG_INF``, ``l = 0``, ``acc = 0``; the
+merge weights it by ``exp(NEG_INF - m_real) == 0.0`` exactly (f32
+underflow), so such splits are *bitwise* absent from the output.  Under
+a mesh the split axis can ride the ``model`` axis
 (``launch.shardings.split_kv_specs``), so each shard reads only its own
 pages and ships one (B, G, R)-sized statistic triple.
 
@@ -40,12 +69,14 @@ Masking is the single ``pos < length`` predicate: decode queries sit at
 position ``length - 1``, so the dense path's causal mask (``kv_pos <=
 q_pos``) and validity mask (``kv_pos < length``) are the same set.
 
-Grid: ``(B, G, splits, blocks_per_split)``, pages innermost
-(accumulator-friendly, "arbitrary"); q/out blocks are whole (R, D) tiles —
-R and D are small (<= head_dim) so VMEM residency is a few KiB per step.
-On this CPU container the kernel runs in interpret mode (the wrapper
-auto-selects), which lowers to plain traced lax ops — jittable, scannable
-inside the serve tick, and partitionable by GSPMD.
+Grid: ``(B, splits, blocks_per_split)``, blocks innermost
+(accumulator-friendly, "arbitrary"); q/out blocks are whole (G, R, D)
+tiles — R and D are small (<= head_dim) so VMEM residency is a few KiB
+per step.  The quantized-pool kernel keeps one page per step and walks
+every column (``ppb = 1``, no clamp, no skip).  On this CPU container
+the kernels run in interpret mode (the wrapper auto-selects), which
+lowers to plain traced lax ops — jittable, scannable inside the serve
+tick, and partitionable by GSPMD.
 """
 
 from __future__ import annotations
@@ -68,9 +99,47 @@ NEG_INF = -1e30
 RAGGED512 = dict(b=4, page_len=16, nb=32, g=2, r=2, d=16,
                  lengths=(512, 300, 64, 17))
 
+# tokens per grid step of the float kernel (block_pages)
+BLOCK_TOKENS = 128
+
+# slot lengths of the static verifier's qwen2.5-14b-l12 decode case: 21
+# live slots, as in the cell, from each side of the 128-token block
+# boundary and lengths of the cell's traffic up to the full 4,160 table,
+# and 11 free (1,656 of the 2,400 pages)
+QWEN14B_LENGTHS = (0, 1, 127, 128, 129, 255, 256, 257, 300, 512, 777,
+                   1000, 1024, 1300, 1536, 1700, 2000, 2100, 2500, 3000,
+                   3300, 4160, 0, 0, 0, 0, 0, 0, 0, 0, 0, 0)
+
+
+def block_pages(page_len: int, nb: int) -> int:
+    """Pages per grid step of the float kernel: a block of about 128
+    tokens (8 pages of 16), never wider than the table.  A function of
+    the shapes alone — never of ``splits``, never a knob — so block
+    boundaries are the same for every split count."""
+    return min(max(1, BLOCK_TOKENS // page_len), nb)
+
+
+def clamped_walk(table, lengths, page_len: int, ppb: int):
+    """The table the float kernel's page operands read: column ``c`` of
+    row ``b`` names ``table[b, min(c, own)]``, where ``own`` is the last
+    live column that operand ``c mod ppb`` walks (``last - (last - i) mod
+    ppb`` for ``i <= last``, else ``last``; ``last = max(ceil(length /
+    page_len) - 1, 0)``).  Past a row's length every operand so names the
+    page it named the step before, and the pipeline issues no copy.  One
+    small XLA gather a call, ``(B, NB)`` int32; numpy inputs give the
+    same table (the static verifier's)."""
+    nb = table.shape[1]
+    last = jnp.maximum((jnp.asarray(lengths) + page_len - 1) // page_len
+                       - 1, 0)[:, None]
+    col = jnp.arange(nb, dtype=jnp.int32)[None, :]
+    i = col % ppb
+    own = jnp.where(i <= last, last - (last - i) % ppb, last)
+    return jnp.take_along_axis(jnp.asarray(table), jnp.minimum(col, own),
+                               axis=1)
+
 
 def paged_attn_specs(b: int, g: int, r: int, d: int, page_len: int,
-                     nb: int, splits: int):
+                     nb: int, splits: int, ppb: int = 1):
     """Grid + BlockSpecs + scratch of one kernel instantiation.
 
     ONE source of truth: :func:`paged_attention_kernel` assembles its
@@ -82,23 +151,24 @@ def paged_attn_specs(b: int, g: int, r: int, d: int, page_len: int,
     Every block's last two dims are the operand's full last two dims, the
     form Mosaic accepts whatever G, R and D are: a K/V block is one whole
     page ``(1, page_len, G, D)`` with all kv heads, q is ``(1, G, R, D)``,
-    and the outputs carry the split axis ahead of ``(G, R[, D])``.
+    and the outputs carry the split axis ahead of ``(G, R[, D])``.  The
+    inputs are q, then ``ppb`` page specs for K, then ``ppb`` for V; the
+    grid walks ``nb / ppb`` blocks of ``ppb`` pages, page operand ``i`` of
+    block ``blk = split * bps + j`` reading column ``blk * ppb + i`` of
+    the prefetched table (the float kernel's is :func:`clamped_walk`).
     """
-    assert nb % splits == 0, (nb, splits)
-    bps = nb // splits
+    assert nb % (splits * ppb) == 0, (nb, splits, ppb)
+    bps = nb // (splits * ppb)
     grid = (b, splits, bps)
-    # the table walk: block index maps dereference the prefetched page
-    # table — page (tab[b, split*bps + j]) streams in, nothing else; the
-    # dense gather never happens
-    page = pl.BlockSpec((1, page_len, g, d),
-                        lambda bi, si, ji, tab, lens:
-                        (tab[bi, si * bps + ji], 0, 0, 0))
-    in_specs = [
-        pl.BlockSpec((1, g, r, d),
-                     lambda bi, si, ji, tab, lens: (bi, 0, 0, 0)),
-        page,
-        page,
-    ]
+
+    def page(i):
+        return pl.BlockSpec((1, page_len, g, d),
+                            lambda bi, si, ji, tab, lens:
+                            (tab[bi, (si * bps + ji) * ppb + i], 0, 0, 0))
+    pages = [page(i) for i in range(ppb)]
+    in_specs = [pl.BlockSpec((1, g, r, d),
+                             lambda bi, si, ji, tab, lens: (bi, 0, 0, 0)),
+                *pages, *pages]
     stat = pl.BlockSpec((1, 1, g, r),
                         lambda bi, si, ji, tab, lens: (bi, si, 0, 0))
     out_specs = [
@@ -115,7 +185,8 @@ def paged_attn_specs(b: int, g: int, r: int, d: int, page_len: int,
 
 def paged_attn_quant_specs(b: int, g: int, r: int, d: int, page_len: int,
                            nb: int, splits: int):
-    """Quantized-pool variant of :func:`paged_attn_specs`.
+    """Quantized-pool variant of :func:`paged_attn_specs`: one page per
+    step, every column walked as the table stands (``ppb = 1``).
 
     Same grid/out/scratch; the K/V operands are packed log2 code pools
     (same (P, page_len, G, D) geometry, int8/int16 elements — the §IV
@@ -152,14 +223,14 @@ def _dequant_block(codes, se, n_bits: int):
 
 def _online_softmax_step(q, k, v, g, pos, length, m_s, l_s, acc_s, *,
                          zero_masked_p: bool):
-    """One page of kv head ``g``: fold ``(k, v)`` into the running
+    """One block of kv head ``g``: fold ``(k, v)`` into the running
     ``(m, l, acc)`` statistics of that head's R query rows.  f32 operands
     multiply at full f32 precision (Mosaic's default passes bf16)."""
     prec = (jax.lax.Precision.HIGHEST if q.dtype == jnp.float32
             else jax.lax.Precision.DEFAULT)
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())), precision=prec,
                             preferred_element_type=jnp.float32)
-    s = s / jnp.sqrt(jnp.float32(q.shape[-1]))    # (R, page_len)
+    s = s / jnp.sqrt(jnp.float32(q.shape[-1]))    # (R, block tokens)
     valid = pos < length
     s = jnp.where(valid, s, NEG_INF)
     m_prev = m_s[g]                               # (R, 1)
@@ -197,32 +268,43 @@ def _flush_stats(o_ref, m_ref, l_ref, m_s, l_s, acc_s):
     l_ref[0, 0] = l_s[..., 0]
 
 
-def _page_positions(page_len: int, bps: int):
-    si = pl.program_id(1)
-    j = pl.program_id(2)
-    base = (si * bps + j) * page_len
-    return base + jax.lax.broadcasted_iota(jnp.int32, (1, page_len), 1)
+def _block_start(block_len: int, bps: int):
+    """The absolute position of this grid step's first token."""
+    return (pl.program_id(1) * bps + pl.program_id(2)) * block_len
 
 
-def _paged_attn_kernel(table_ref, lens_ref,      # scalar prefetch
+def _positions(start, block_len: int):
+    """(1, block_len) absolute token positions from ``start``."""
+    return start + jax.lax.broadcasted_iota(jnp.int32, (1, block_len), 1)
+
+
+def _paged_attn_kernel(walk_ref, lens_ref,       # scalar prefetch
                        q_ref,                    # (1, G, R, D)
-                       k_ref, v_ref,             # (1, page_len, G, D)
-                       o_ref,                    # (1, 1, G, R, D) f32
-                       m_ref, l_ref,             # (1, 1, G, R) f32
-                       m_s, l_s, acc_s,          # VMEM scratch
-                       *, page_len: int, bps: int):
+                       *refs,                    # ppb K pages, ppb V pages
+                       page_len: int, bps: int, ppb: int):
+    # refs: ppb K then ppb V (1, page_len, G, D) pages, then the outputs
+    # o (1, 1, G, R, D) and m, l (1, 1, G, R) f32, then the VMEM scratch
+    k_refs, v_refs = refs[:ppb], refs[ppb:2 * ppb]
+    o_ref, m_ref, l_ref, m_s, l_s, acc_s = refs[2 * ppb:]
     j = pl.program_id(2)
 
     @pl.when(j == 0)
     def _init():
         _init_stats(m_s, l_s, acc_s)
 
-    pos = _page_positions(page_len, bps)
+    start = _block_start(ppb * page_len, bps)
     length = lens_ref[pl.program_id(0)]
-    for g in range(q_ref.shape[1]):               # static: G kv heads
-        _online_softmax_step(q_ref[0, g], k_ref[0, :, g, :],
-                             v_ref[0, :, g, :], g, pos, length,
-                             m_s, l_s, acc_s, zero_masked_p=False)
+
+    # a block wholly past the length: its pages were not copied (the
+    # clamped walk repeats the previous step's) and it folds nothing
+    @pl.when(start < length)
+    def _block():
+        pos = _positions(start, ppb * page_len)
+        for g in range(q_ref.shape[1]):           # static: G kv heads
+            k = jnp.concatenate([r[0, :, g, :] for r in k_refs], axis=0)
+            v = jnp.concatenate([r[0, :, g, :] for r in v_refs], axis=0)
+            _online_softmax_step(q_ref[0, g], k, v, g, pos, length,
+                                 m_s, l_s, acc_s, zero_masked_p=False)
 
     @pl.when(j == bps - 1)
     def _flush():
@@ -238,19 +320,22 @@ def _partials_shape(b: int, g: int, r: int, d: int, splits: int):
 def paged_attention_kernel(qg: jnp.ndarray, k_pool: jnp.ndarray,
                            v_pool: jnp.ndarray, page_table: jnp.ndarray,
                            lengths: jnp.ndarray, *, splits: int = 1,
-                           interpret: bool = False):
+                           ppb: int, interpret: bool = False):
     """qg (B, G, R, D) grouped decode queries; k/v pool (P, page_len, G,
-    D); page_table (B, NB) int32 with NB divisible by ``splits``; lengths
-    (B,) int32.  Returns partial ``(o, m, l)``: o (B, splits, G, R, D)
-    f32, m/l (B, splits, G, R) f32 — merge with
+    D); page_table (B, NB) int32 with NB divisible by ``splits * ppb``
+    (``ppb`` pages per grid step, :func:`block_pages` of the unpadded
+    table), read through its :func:`clamped_walk`; lengths (B,) int32.  Returns partial ``(o, m, l)``: o (B,
+    splits, G, R, D) f32, m/l (B, splits, G, R) f32 — merge with
     :func:`ops.merge_split_softmax`."""
     b, g, r, d = qg.shape
     page_len = k_pool.shape[1]
     nb = page_table.shape[1]
     grid, in_specs, out_specs, scratch_shapes, bps = paged_attn_specs(
-        b, g, r, d, page_len, nb, splits)
+        b, g, r, d, page_len, nb, splits, ppb)
+    walk = clamped_walk(page_table, lengths, page_len, ppb)
 
-    kern = functools.partial(_paged_attn_kernel, page_len=page_len, bps=bps)
+    kern = functools.partial(_paged_attn_kernel, page_len=page_len, bps=bps,
+                             ppb=ppb)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=grid,
@@ -265,7 +350,7 @@ def paged_attention_kernel(qg: jnp.ndarray, k_pool: jnp.ndarray,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
-    )(page_table, lengths, qg, k_pool, v_pool)
+    )(walk, lengths, qg, *[k_pool] * ppb, *[v_pool] * ppb)
 
 
 def _paged_attn_quant_kernel(table_ref, lens_ref,  # scalar prefetch
@@ -288,7 +373,7 @@ def _paged_attn_quant_kernel(table_ref, lens_ref,  # scalar prefetch
     def _init():
         _init_stats(m_s, l_s, acc_s)
 
-    pos = _page_positions(page_len, bps)
+    pos = _positions(_block_start(page_len, bps), page_len)
     length = lens_ref[pl.program_id(0)]
     for g in range(q_ref.shape[1]):               # static: G kv heads
         k = _dequant_block(k_ref[0, :, g, :], ks_ref[0, 0, g], n_bits)
@@ -366,11 +451,15 @@ def audit_specs():
 
     Enumerates the audit matrix — the ragged512 bench geometry (the
     gather_saved_frac gate), the serve-smoke geometry the scheduler's tick
-    actually compiles (page_len 4, the distinctive 34-page pool), and a
-    GQA edge case — across splits and pool dtypes.  Each instantiation
-    hands the verifier the SAME BlockSpecs :func:`paged_attn_specs` gives
-    ``pallas_call``, plus the concrete scalar-prefetch operands (table,
-    lengths) the index maps dereference.
+    actually compiles (page_len 4, the distinctive 34-page pool), a GQA
+    edge case and the qwen2.5-14b-l12 serving cell's geometry — across
+    splits and pool dtypes.  Each instantiation hands the verifier the
+    SAME BlockSpecs :func:`paged_attn_specs` gives ``pallas_call`` (the
+    float kernel's ``ppb`` page operands per pool named ``k_pool.<i>`` /
+    ``v_pool.<i>``), plus the concrete scalar-prefetch operands (the
+    float kernel's :func:`clamped_walk` of the padded table, or the
+    quantized kernel's table; lengths) the index maps dereference;
+    ``meta["table"]`` is the page table before padding.
     """
     import numpy as np
 
@@ -390,6 +479,11 @@ def audit_specs():
         ("gqa_edge.s2",
          dict(b=2, page_len=8, nb=4, g=3, r=4, d=8,
               lengths=(7, 32)), 2, jnp.bfloat16, None),
+        # the qwen2.5-14b-l12 serving cell: 32 slots over a 260-page
+        # table, 2,400 pages, bf16
+        ("qwen14b_decode.s1",
+         dict(b=32, page_len=16, nb=260, g=8, r=5, d=128,
+              lengths=QWEN14B_LENGTHS), 1, jnp.bfloat16, 2400),
     ]
     out = []
     for name, geo, splits, dtype, n_pages in cases:
@@ -399,14 +493,20 @@ def audit_specs():
         if n_pages is None:
             n_pages = 1 + b * nb
         table = make_page_table(lens, nb, pl_)
+        assert table.max() < n_pages, (name, table.max(), n_pages)
+        # what the kernel reads: the table padded with trash columns to
+        # whole blocks of every split, as ops pads it, then clamped
+        ppb = block_pages(pl_, nb)
+        padded = np.pad(table, ((0, 0), (0, (-nb) % (splits * ppb))))
+        walk = np.asarray(clamped_walk(padded, lens, pl_, ppb))
         grid, in_specs, out_specs, scratch, bps = paged_attn_specs(
-            b, g, r, d, pl_, nb, splits)
+            b, g, r, d, pl_, walk.shape[1], splits, ppb)
         pool_shape = (n_pages, pl_, g, d)
-        inputs = (
-            make_operand("q", (b, g, r, d), dtype, in_specs[0]),
-            make_operand("k_pool", pool_shape, dtype, in_specs[1]),
-            make_operand("v_pool", pool_shape, dtype, in_specs[2]),
-        )
+        inputs = [make_operand("q", (b, g, r, d), dtype, in_specs[0])]
+        for k, pool in enumerate(("k_pool", "v_pool")):
+            for i in range(ppb):
+                inputs.append(make_operand(f"{pool}.{i}", pool_shape, dtype,
+                                           in_specs[1 + k * ppb + i]))
         outputs = (
             make_operand("o", (b, splits, g, r, d), jnp.float32,
                          out_specs[0]),
@@ -415,11 +515,12 @@ def audit_specs():
         )
         out.append(KernelInstantiation(
             kernel="paged_attention", case=name, grid=grid,
-            inputs=inputs, outputs=outputs,
+            inputs=tuple(inputs), outputs=outputs,
             scratch=tuple(scratch_entry(s) for s in scratch),
-            scalars=(table, lens),
-            meta=dict(page_len=pl_, bps=bps, splits=splits, n_pages=n_pages,
-                      trash_page=0, table=table, lengths=lens),
+            scalars=(walk, lens),
+            meta=dict(page_len=pl_, bps=bps, ppb=ppb, splits=splits,
+                      n_pages=n_pages, trash_page=0, table=table,
+                      lengths=lens),
         ))
 
     # quantized-pool variants (ServeScheduler kv_quant=True): same table

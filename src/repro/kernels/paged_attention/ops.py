@@ -1,8 +1,9 @@
 """Public jit'd wrapper for the paged-attention decode kernel.
 
-Responsibilities: grouped-query reshape, split-KV table padding (trailing
-trash-page columns make the block count divisible by ``splits`` — padded
-blocks sit past every valid position, so they mask to exact zeros), the
+Responsibilities: grouped-query reshape, table padding (trailing
+trash-page columns make the column count a multiple of ``splits`` times
+the pages per grid step — padded columns sit past every valid position,
+so the kernel never reads them), the
 cross-split partial-softmax merge, the split-KV sharding hints, and the
 gather-traffic accounting benchmarks report (the paper-§IV "avoided
 accesses" image of the kernel, like ``bitplane_matmul.ops
@@ -17,7 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels.paged_attention.kernel import (NEG_INF,
+from repro.kernels.paged_attention.kernel import (NEG_INF, block_pages,
                                                   paged_attention_kernel,
                                                   paged_attention_quant_kernel)
 from repro.models.sharding import kernel_call, kernel_mesh, shard
@@ -40,11 +41,12 @@ def merge_split_softmax(m: jnp.ndarray, l: jnp.ndarray, acc: jnp.ndarray,
     splits, each split reweights by ``exp(m - M)`` — for a split that saw
     no valid token ``m == NEG_INF`` (finite, -1e30) and the weight
     underflows to exactly 0.0 in f32, so its junk partials are *bitwise*
-    absent from the sum.  A row with no valid token anywhere keeps
-    ``l_tot`` positive (every split contributes its uniform-junk ``l``),
-    so the output is finite garbage — never NaN — exactly like the dense
-    path's softmax over an all-NEG_INF row; such rows are inactive slots
-    whose outputs the serve tick discards.
+    absent from the sum.  A row with no valid token anywhere gives
+    finite garbage, never NaN: the float kernel computes nothing for it
+    (``l == 0``, ``acc == 0``, and the ``1e-30`` floor makes the output
+    0), the quantized kernel's uniform-junk ``l`` keeps ``l_tot``
+    positive; such rows are inactive slots whose outputs the serve tick
+    discards.
     """
     axis = axis % m.ndim          # acc has a trailing extra dim, so resolve
     m_max = jnp.max(m, axis=axis, keepdims=True)  # negative axes against m
@@ -79,15 +81,18 @@ def _paged_decode_attention(q, k_pool, v_pool, page_table, lengths, *,
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     b, _, h, d = q.shape
-    g = k_pool.shape[2]
+    page_len, g = k_pool.shape[1], k_pool.shape[2]
     nb = page_table.shape[1]
-    pad = (-nb) % splits
+    # pages per grid step, from the unpadded table: the same blocks for
+    # every split count
+    ppb = block_pages(page_len, nb)
+    pad = (-nb) % (splits * ppb)
     if pad:
         # trash-page columns: their positions sit past any valid length,
-        # so the kernel masks them to exact zeros like any junk tail
+        # so the kernel's clamped walk never names them
         page_table = jnp.pad(page_table, ((0, 0), (0, pad)))
     qg = q.reshape(b, 1, g, h // g, d)[:, 0]             # (B, G, R, D)
-    kern = functools.partial(paged_attention_kernel, splits=splits,
+    kern = functools.partial(paged_attention_kernel, splits=splits, ppb=ppb,
                              interpret=interpret)
     o, m, l = kernel_call(kern, kmesh, (_Q, _POOL, _POOL, _ROW, _LEN),
                           _PARTIALS, qg, k_pool, v_pool,

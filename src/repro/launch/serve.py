@@ -247,8 +247,9 @@ def _serve_continuous(cfg, params, args, mesh):
 
 def _counter_summary(c) -> str:
     """One line from ``ServeScheduler.counters()``: admission stalls, the
-    share of chunk-slab rows that carried a prompt token, and the share of
-    reserved KV page-ticks that held no token yet."""
+    share of chunk-slab rows that carried a prompt token, the share of
+    reserved KV page-ticks that held no token yet, and the share of the
+    paged-attention kernel's grid blocks that it computes."""
     out = f"{c['admit_stalls']} admission stalls in {c['ticks']} ticks"
     if c["chunk_slab_rows"]:
         live = c["chunk_tokens"] / c["chunk_slab_rows"]
@@ -258,6 +259,10 @@ def _counter_summary(c) -> str:
         idle = 1 - c["kv_page_ticks_written"] / c["kv_page_ticks_reserved"]
         out += (f"; KV pages {100 * idle:.1f}% reserved but unwritten "
                 f"(page-ticks, pool of {c['kv_pages_capacity']})")
+    if c["attn_kv_blocks_grid"]:
+        live = c["attn_kv_blocks_live"] / c["attn_kv_blocks_grid"]
+        out += (f"; attention kernel blocks {100 * live:.1f}% live "
+                f"({c['attn_kv_blocks_live']}/{c['attn_kv_blocks_grid']})")
     return out
 
 

@@ -84,6 +84,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.paged_attention.kernel import block_pages
 from repro.models.model import ModelConfig, init_caches, init_paged_pool
 from repro.serving import engine
 from repro.serving.config import ServeConfig
@@ -374,7 +375,8 @@ class ServeScheduler:
         # cumulative scheduler counters (read through counters())
         self._counters = {"chunk_tokens": 0, "chunk_slab_rows": 0,
                           "admit_stalls": 0, "kv_page_ticks_reserved": 0,
-                          "kv_page_ticks_written": 0}
+                          "kv_page_ticks_written": 0,
+                          "attn_kv_blocks_grid": 0, "attn_kv_blocks_live": 0}
 
         # sharding specs: pool batch on `data`, kv-seq/ssm-heads on `model`,
         # per-slot (B,) lengths on `data`; params get the TP rules (incl.
@@ -926,7 +928,14 @@ class ServeScheduler:
         request the page pool could not yet hold), and
         ``kv_page_ticks_reserved`` / ``kv_page_ticks_written`` (the two
         page gauges below summed over the ticks, each read as the tick
-        ends).  Gauges of the paged KV pool, zero without one:
+        ends), and ``attn_kv_blocks_grid`` / ``attn_kv_blocks_live`` (per
+        decode step the tick programs ran, the float paged-attention
+        kernel's grid blocks over every slot, and those holding at least
+        one token of a decoding row; free and prefilling slots, whose
+        outputs the tick discards, count none; one kernel call's worth
+        per step, as every layer walks the same table; zero without that
+        kernel).  Gauges of the
+        paged KV pool, zero without one:
         ``kv_pages_capacity`` (usable pages), ``kv_pages_reserved`` (pages
         held by live slots) and ``kv_pages_written`` (those holding at
         least one written token of a live slot); a prefix page that
@@ -954,6 +963,26 @@ class ServeScheduler:
             reserved += len(s.pages) - n
             written += blocks_for_tokens(cached, self.page_len) - n
         return reserved + len(shared), written + len(shared)
+
+    def _count_attn_blocks(self, decode_mask: np.ndarray) -> None:
+        """Add this tick's decode steps to ``attn_kv_blocks_grid`` and
+        ``attn_kv_blocks_live``.  At step ``t`` a decoding row's kernel
+        length is its prompt, the tokens it had at the tick's start and
+        ``t + 1`` (the token that step writes); the grid covers the table
+        padded to whole blocks of every split."""
+        nb = self._table.shape[1]
+        ppb = block_pages(self.page_len, nb)
+        per_row = (nb + (-nb) % (self.attn_splits * ppb)) // ppb
+        block = ppb * self.page_len
+        live = 0
+        for i in np.flatnonzero(decode_mask):
+            s = self._slots[i]
+            base = int(s.req.prompt.size) + len(s.tokens)
+            for t in range(self.tick_steps):
+                live += blocks_for_tokens(base + t + 1, block)
+        self._counters["attn_kv_blocks_grid"] += (
+            self.tick_steps * self.max_slots * per_row)
+        self._counters["attn_kv_blocks_live"] += live
 
     def step_tick(self) -> bool:
         """Admit into every free slot, feed one prompt chunk to every
@@ -1034,6 +1063,9 @@ class ServeScheduler:
                  and (s.phase == "decode"
                       or (chunk_rows and finishing[i] and not defer[i]))
                  for i, s in enumerate(self._slots)])
+            if (self.paged and self.attn_kernel != "off"
+                    and not self.kv_quant and decode_mask.any()):
+                self._count_attn_blocks(decode_mask)
 
             if self.first_logits is not None:
                 for i in np.flatnonzero(decode_mask):

@@ -218,6 +218,46 @@ class TestTrafficModel:
         assert disagreements
         assert disagreements[0].rule == "kernel-traffic-model"
 
+    def test_cell_geometry_walk(self):
+        """The qwen2.5-14b-l12 cell's geometry: 33 blocks of 8 pages a
+        slot; the useful fetches are exactly its live pages, and the
+        copies beyond them stay within ``ppb`` pages a slot."""
+        inst = _shipped("paged_attention/qwen14b_decode.s1")
+        assert inst.grid == (32, 1, 33) and inst.meta["ppb"] == 8
+        rec, disagreements = kernel_rules.static_traffic(inst)
+        assert not disagreements
+        lens = np.asarray(inst.meta["lengths"])
+        assert rec["fetches"]["k_pool"] == int(np.sum(-(-lens // 16))) \
+            == 1656
+        page = 16 * 8 * 128 * 2                     # one bf16 K or V page
+        extra = rec["bytes_issued"] - rec["bytes_read"]
+        assert 0 < extra <= 2 * 8 * 32 * page
+
+    def test_dead_columns_not_copied(self):
+        """Real (stale) pages in every dead column of the page table: the
+        clamped walk of it issues the same copies as of trash columns,
+        while walking the table as it stands copies them and trips the
+        issued-copies bound."""
+        from repro.kernels.paged_attention.kernel import clamped_walk
+        inst = _shipped("paged_attention/ragged512.s1")
+        meta = inst.meta
+        table = np.array(meta["table"])
+        for bi, ln in enumerate(meta["lengths"]):
+            n = -(-int(ln) // meta["page_len"])
+            table[bi, n:] = 1 + np.arange(table.shape[1] - n)
+        walk = np.asarray(clamped_walk(table, meta["lengths"],
+                                       meta["page_len"], meta["ppb"]))
+        base, _ = kernel_rules.static_traffic(inst)
+        for scalars, fires in ((walk, False), (table, True)):
+            stale = dataclasses.replace(inst, scalars=(scalars,)
+                                        + inst.scalars[1:])
+            rec, disagreements = kernel_rules.static_traffic(stale)
+            assert any("dead blocks are being fetched" in f.detail
+                       for f in disagreements) == fires
+            if not fires:
+                assert not disagreements
+                assert rec["bytes_issued"] == base["bytes_issued"]
+
     def test_bitplane_static_matches_runtime_counters(self):
         import jax.numpy as jnp
 

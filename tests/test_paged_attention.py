@@ -45,6 +45,7 @@ needs_hypothesis = pytest.mark.skipif(
                                 "lattice covers the same invariants)")
 
 from repro.configs import get_smoke
+from repro.kernels.paged_attention.kernel import block_pages
 from repro.kernels.paged_attention.ops import (gather_traffic_counts,
                                                merge_split_softmax,
                                                paged_decode_attention)
@@ -152,6 +153,67 @@ class TestKernelVsReference:
                                        np.asarray(ref)[live], **F32_TOL)
             assert np.isfinite(np.asarray(out)).all()
         check()
+
+
+class TestBlockWalk:
+    """The float kernel walks ``block_pages`` pages per grid step, each
+    page operand clamped to the row's last live page, and skips blocks
+    wholly past the length."""
+
+    def test_block_pages(self):
+        assert block_pages(16, 260) == 8        # the qwen2.5-14b-l12 cell
+        assert block_pages(16, 4) == 4          # never wider than the table
+        assert block_pages(4, 64) == 32
+        assert block_pages(256, 16) == 1
+
+    @pytest.mark.parametrize("page_len,nb", [(16, 20), (8, 40)])
+    @pytest.mark.parametrize("splits", [1, 2, 3])
+    def test_block_boundary_lattice(self, page_len, nb, splits):
+        """Lengths on each side of the block boundary, two blocks and the
+        full table, with ``nb`` not a multiple of ``ppb`` (8 and 16 pages
+        a block here) so the table pads to whole blocks of every split."""
+        ppb = block_pages(page_len, nb)
+        assert nb % ppb
+        blk = ppb * page_len
+        lengths = [0, 1, blk - 1, blk, blk + 1, 2 * blk, nb * page_len]
+        rng = np.random.default_rng(page_len * 10 + splits)
+        q, k, v, table, lens = _make_case(rng, page_len=page_len, nb=nb,
+                                          g=2, r=2, d=8, lengths=lengths,
+                                          poison=1e4)
+        out = np.asarray(paged_decode_attention(q, k, v, table, lens,
+                                                splits=splits))
+        ref = np.asarray(paged_attention_reference(q, k, v, table, lens))
+        live = np.asarray(lens) > 0
+        np.testing.assert_allclose(out[live], ref[live], **F32_TOL)
+        assert np.isfinite(out).all()
+
+    @pytest.mark.parametrize("splits", [1, 2, 3])
+    def test_dead_columns_never_read(self, splits):
+        """Table columns past each row's length point at REAL pages full
+        of poison (not at the trash page): live rows are bitwise what they
+        are with a clean table, so no dead block is read or folded in.  A
+        NaN page that were read would turn its zero weight into NaN."""
+        page_len, nb = 4, 40                      # 32 pages a block
+        lengths = [1, 5, 127, 128, 129, 150]
+        rng = np.random.default_rng(31)
+        q, k, v, table, lens = _make_case(rng, page_len=page_len, nb=nb,
+                                          g=2, r=2, d=8, lengths=lengths)
+        base = np.asarray(paged_decode_attention(q, k, v, table, lens,
+                                                 splits=splits))
+        table = np.asarray(table).copy()
+        k, v = np.asarray(k).copy(), np.asarray(v).copy()
+        n_pages = k.shape[0]
+        for poison in (1e4, -1e4, np.nan):
+            kp = np.concatenate([k, np.full_like(k[:nb], poison)])
+            vp = np.concatenate([v, np.full_like(v[:nb], poison)])
+            stale = table.copy()
+            for i, ln in enumerate(lengths):
+                n = -(-ln // page_len)
+                stale[i, n:] = n_pages + np.arange(nb - n)
+            out = np.asarray(paged_decode_attention(
+                q, jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(stale),
+                lens, splits=splits))
+            np.testing.assert_array_equal(out, base)
 
 
 class TestTrashPageIsolation:

@@ -5,8 +5,9 @@ tick's two parts, ``attn`` / ``mlp`` / ``head`` inside the model) under
 their unchanged jitted names; ``step_tick`` writes ``serve.*`` host spans
 that nest inside a caller's span in phase order; and
 ``ServeScheduler.counters()`` counts the chunk slab's rows, admission
-stalls and the KV pages the live slots hold and have written, as the page
-tables and the device's cache lengths say.
+stalls, the KV pages the live slots hold and have written, as the page
+tables and the device's cache lengths say, and the paged-attention
+kernel's grid blocks and those it computes.
 """
 
 import glob
@@ -112,6 +113,8 @@ def test_chunk_counters_count_fed_tokens_and_slab_rows(setup):
     assert c["chunk_tokens"] == sum(lens)
     assert c["chunk_slab_rows"] == len(calls) * 2 * 8
     assert c["admit_stalls"] == 0
+    # the gather path runs no kernel
+    assert c["attn_kv_blocks_grid"] == c["attn_kv_blocks_live"] == 0
 
 
 def test_admit_stalls_count_ticks_waiting_on_the_pool(setup):
@@ -167,6 +170,31 @@ def test_kv_page_gauges_match_the_tables(setup):
                 <= c["kv_pages_capacity"])
     assert shared_seen
     assert sched.counters()["kv_pages_reserved"] == 0
+
+
+def test_attn_block_counters_by_hand(setup):
+    """``attn_kv_blocks_grid`` / ``attn_kv_blocks_live`` on a small kernel
+    scheduler, worked out by hand.  page_len 8 and a 64-column table make
+    a block of 16 pages (128 tokens); 3 splits pad the table to 96
+    columns, 6 blocks a row; 2 slots and 2 steps a tick give 24 grid
+    blocks a tick.  Both requests prefill in the first tick and decode 12
+    tokens in 6 ticks.  At step ``t`` a row's kernel length is its prompt
+    plus the tokens before the step plus one: 121..132 for the 120-token
+    prompt (one block up to 128 tokens, two after), 6..17 for the 5-token
+    one (always one)."""
+    cfg, params = setup
+    sched = _sched(cfg, params, max_len=512, buckets=(128,),
+                   attn_kernel="pallas", attn_splits=3)
+    assert sched._table.shape[1] == 64
+    sched.submit(_prompt(cfg, 120), max_new=12)
+    sched.submit(_prompt(cfg, 5, 1), max_new=12)
+    seen = _ticks(sched)
+    assert [c["attn_kv_blocks_grid"] for c in seen] == [24, 48, 72, 96,
+                                                        120, 144]
+    # per tick: long row 1+1 (121-122), 1+1, 1+1, 1+1 (127-128), 2+2, 2+2;
+    # short row 1+1 every tick
+    assert [c["attn_kv_blocks_live"] for c in seen] == [4, 8, 12, 16, 22,
+                                                        28]
 
 
 def test_dense_scheduler_counters_have_no_pages(setup):

@@ -1,4 +1,6 @@
-"""The Pallas kernels compile for a TPU v5e chip, at smollm-135m widths.
+"""The Pallas kernels compile for a TPU v5e chip, at smollm-135m widths
+and, for the float paged-attention kernel, at the qwen2.5-14b-l12
+serving cell's.
 
 Compiled ahead of time for a described (not attached) ``v5e:2x2``
 topology: Mosaic refuses here what interpret mode accepts — block shapes
@@ -72,6 +74,19 @@ def test_paged_attention(spec, dtype, splits):
                    spec((N_PAGES, PAGE_LEN, G, D), dtype),
                    spec((N_PAGES, PAGE_LEN, G, D), dtype),
                    spec((B, NB), jnp.int32), spec((B,), jnp.int32))
+
+
+def test_paged_attention_cell_geometry(spec):
+    """The float kernel at the qwen2.5-14b-l12 serving cell's geometry:
+    32 slots, 40/8 heads of 128, a 260-page table (33 blocks of 8 pages)
+    over a 2,400-page bf16 pool."""
+    b, g, r, d, nb, n_pages = 32, 8, 5, 128, 260, 2400
+    fn = functools.partial(paged_decode_attention, splits=1,
+                           interpret=False)
+    _assert_mosaic(fn, spec((b, 1, g * r, d), jnp.bfloat16),
+                   spec((n_pages, PAGE_LEN, g, d), jnp.bfloat16),
+                   spec((n_pages, PAGE_LEN, g, d), jnp.bfloat16),
+                   spec((b, nb), jnp.int32), spec((b,), jnp.int32))
 
 
 @pytest.mark.parametrize("splits", [1, 2])
