@@ -30,18 +30,17 @@ def main(name: str, n_pages=None, only=None) -> None:
     from jax.sharding import SingleDeviceSharding
 
     from bench import run
-    from bench.lib import spec as speclib
-    from bench.lib.weights import weight_shapes
     from repro.models.model import init_paged_pool
 
     jax.config.update("jax_enable_compilation_cache", False)
     manifest = run.load_manifest()
     conf = next(c for c in manifest["configs"] if c["name"] == name)
-    doc = speclib.load_doc(HERE.parent / conf["file"])
-    spec = speclib.model_spec(doc)
+    doc = run.load_doc(run.ROOT / conf["file"])
+    arch = run.load_arch(run.ROOT, doc)
+    spec = arch.model_spec(doc)
     small = dict(doc, serve=dict(doc["serve"], n_pages=8))
-    w = weight_shapes(spec)
-    cfg, serve, sched = run.build(small, spec, w)
+    w = arch.weight_shapes(spec)
+    cfg, serve, sched = run.build(arch, small, spec, w)
     real = dataclasses.replace(
         serve, n_pages=n_pages or doc["serve"].get("n_pages"))
     n_pages = real.resolved_n_pages()

@@ -1,3 +1,5 @@
-"""The benchmark's yardstick: load generation, trace reduction, peaks, FLOP
-and byte counts and the plain reference.  Nothing here imports the program
-under test (``repro``); later changes to the program cannot move it."""
+"""The benchmark's yardstick: load generation, trace reduction, peaks and
+the numerics that every architecture's plain reference shares (each
+architecture's own reference, weights and FLOP and byte counts are in
+``bench/arch/``).  Nothing here imports the program under test
+(``repro``); later changes to the program cannot move it."""

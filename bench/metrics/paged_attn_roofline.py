@@ -1,12 +1,12 @@
 """Paged-attention kernel time against its roofline.
 
 Per kernel call (one layer, one decode step) the bound is the larger of
-(K/V bytes of the live rows' true context lengths, plus q and out) / HBM
-peak and FLOPs / bf16 peak; the context lengths are tracked host-side per
+bytes / HBM peak and FLOPs / bf16 peak, both counted by the architecture
+module's ``paged_attn_cost`` (the K/V bytes of the live rows' true context
+lengths, plus q and out); the context lengths are tracked host-side per
 tick.  Summed over the traced ticks, divided by the summed device time of
 the kernel's events in the programs that run the decode scan."""
 
-from bench.lib import flops as F
 from bench.lib import trace as T
 from bench.lib.peaks import peaks_for
 
@@ -31,7 +31,8 @@ def read(ctx):
         pk = pk or peaks_for(ctx.device_kind)
         for j in range(steps):
             ctxs = [plen + done + j + 1 for plen, done, _ in t.decode]
-            fl, by = F.paged_attn_cost(ctx.spec, ctxs, itemsize, itemsize)
+            fl, by = ctx.arch.paged_attn_cost(ctx.spec, ctxs, itemsize,
+                                              itemsize)
             bound += ctx.spec.n_layers * max(fl / pk.bf16_flops,
                                              by / pk.hbm_bw)
     return 100.0 * bound / kern if kern > 0 else None

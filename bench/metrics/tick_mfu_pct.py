@@ -1,31 +1,30 @@
 """Model FLOPs of the tokens the traced ticks processed, over the device's
 busy time inside their ``step_tick`` spans times the chip's bf16 peak.
 
-Counted per token whatever implements it: 2 x the matmul weights of every
-layer, the LM head for each token that yields logits (every decoded token,
-and a prompt's last token), and 4 x layers x heads x head_dim x context
-for attention.  Padded slab rows and discarded decode steps are not model
-work."""
+Counted per token by the configuration's architecture module
+(``decode_flops``, ``prefill_flops``, ``head_params``), whatever implements
+it: the LM head only for each token that yields logits (every decoded
+token, and a prompt's last token).  Padded slab rows and discarded decode
+steps are not model work."""
 
-from bench.lib import flops as F
 from bench.lib import trace as T
 from bench.lib.peaks import peaks_for
 
 
-def tick_flops(spec, t):
+def tick_flops(arch, spec, t):
     f = 0.0
     for plen, done, kept in t.decode:
         ctx0 = plen + done          # the cache before this tick's steps
         ctx_sum = kept * ctx0 + kept * (kept + 1) // 2
-        f += F.decode_flops(spec, ctx_sum, kept)
+        f += arch.decode_flops(spec, ctx_sum, kept)
     for start, n in t.chunk:
-        f += F.prefill_flops(spec, start, n, 0)
+        f += arch.prefill_flops(spec, start, n, 0)
     for n in t.prefill:
-        f += F.prefill_flops(spec, 0, n, 0)
+        f += arch.prefill_flops(spec, 0, n, 0)
     # the first logits of every prompt that finished ingesting this tick
     # are the ones its first decoded token was read from
     firsts = sum(1 for _, done, _ in t.decode if done == 0)
-    return f + 2.0 * firsts * F.head_params(spec)
+    return f + 2.0 * firsts * arch.head_params(spec)
 
 
 def read(ctx):
@@ -34,5 +33,5 @@ def read(ctx):
     busy = sum(T.busy(ctx.trace, t.a, t.b) for t in ctx.ticks)
     if busy <= 0:
         return None
-    work = sum(tick_flops(ctx.spec, t) for t in ctx.ticks)
+    work = sum(tick_flops(ctx.arch, ctx.spec, t) for t in ctx.ticks)
     return 100.0 * work / (busy * peaks_for(ctx.device_kind).bf16_flops)

@@ -3,8 +3,10 @@
     python3 bench/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
 
 Everything is found by name: the cell in ``BENCHMARK.json``, its
-configuration in the file that the manifest names, its traffic mix in
-``bench/traffic/<mix>.json`` and each metric's reader in
+configuration in the file that the manifest names, its architecture in
+``bench/arch/<model_type>.py`` (``model_type`` as the configuration file
+gives it; the interface is in ``bench/arch/__init__.py``), its traffic mix
+in ``bench/traffic/<mix>.json`` and each metric's reader in
 ``bench/metrics/<stem>.py`` (``<stem>`` is the metric's name before its
 first ``.``).  Set-up builds the program's serving scheduler from the
 configuration, makes the weights on the device from the seed, warms up
@@ -29,6 +31,7 @@ import importlib.util
 import json
 import math
 import os
+import re
 import shutil
 import sys
 import tempfile
@@ -42,7 +45,7 @@ ROOT = BENCH.parent
 sys.path.insert(0, str(ROOT))
 sys.path.insert(1, str(ROOT / "src"))
 
-from bench.lib import loadgen, reference, spec as speclib  # noqa: E402
+from bench.lib import loadgen  # noqa: E402
 from bench.lib.drive import drive  # noqa: E402
 
 
@@ -88,33 +91,49 @@ def metrics_for(manifest: dict, workload: str, trace: bool):
                 else m["moves"] in names)]
 
 
+def _load(name: str, path: Path):
+    found = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(found)
+    sys.modules[name] = mod          # a dataclass looks its module up there
+    found.loader.exec_module(mod)
+    return mod
+
+
 def reader(root: Path, name: str):
     stem = name.split(".")[0]
-    path = root / "bench" / "metrics" / f"{stem}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{stem}",
-                                                  path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load(f"bench_metric_{stem}",
+                 root / "bench" / "metrics" / f"{stem}.py").read
+
+
+def load_doc(path: Path) -> dict:
+    return json.loads(Path(path).read_text())
+
+
+def load_arch(root: Path, doc: dict):
+    """The architecture module that the configuration's ``model_type``
+    names: ``<root>/bench/arch/<model_type>.py``."""
+    where = root / "bench" / "arch"
+    mt = doc.get("model_type")
+    if mt is None:
+        raise SystemExit(f"configuration {doc.get('name')!r} has no "
+                         f"\"model_type\" key, which names its architecture "
+                         f"module {where}/<model_type>.py")
+    path = where / f"{mt}.py"
+    if not re.fullmatch(r"\w+", str(mt)) or not path.is_file():
+        raise SystemExit(f"configuration {doc.get('name')!r}: \"model_type\" "
+                         f"{mt!r} names no architecture module: {path} "
+                         f"not found")
+    return _load(f"bench_arch_{mt}", path)
 
 
 # ------------------------------------------------------------- program
 
-def build(doc: dict, spec, weights):
+def build(arch, doc: dict, spec, weights):
     """The program under test: its model config and its serving scheduler
     over ``weights``."""
-    import jax.numpy as jnp
-
-    from repro.models.model import ModelConfig
     from repro.serving import ServeConfig, ServeScheduler
 
-    cfg = ModelConfig(
-        name=spec.name, d_model=spec.d_model, n_layers=spec.n_layers,
-        d_ff=spec.d_ff, vocab_size=spec.vocab_size, n_heads=spec.n_heads,
-        n_kv_heads=spec.n_kv_heads, head_dim=spec.head_dim,
-        qkv_bias=spec.qkv_bias, rope_theta=spec.rope_theta,
-        norm_eps=spec.norm_eps, tie_embeddings=spec.tie_embeddings,
-        dtype={"bfloat16": jnp.bfloat16, "float32": jnp.float32}[spec.dtype])
+    cfg = arch.program_config(spec)
     serve = ServeConfig(**doc["serve"])
     return cfg, serve, ServeScheduler(cfg, weights, serve)
 
@@ -229,10 +248,11 @@ def setup_cell(workload: str, seed: int, root: Path = ROOT, fault=None):
 
     manifest = load_manifest(root)
     wl, conf = cell(manifest, workload)
-    doc = speclib.load_doc(root / conf["file"])
+    doc = load_doc(root / conf["file"])
+    arch = load_arch(root, doc)
     mix = json.loads((root / "bench" / "traffic"
                       / f"{wl['traffic']}.json").read_text())
-    spec = speclib.model_spec(doc)
+    spec = arch.model_spec(doc)
     chips = int(wl["chips"])
     devices = jax.devices()[:chips]
 
@@ -244,18 +264,18 @@ def setup_cell(workload: str, seed: int, root: Path = ROOT, fault=None):
                       "highest" if spec.dtype == "float32" else None)
     counter = CompileCounter()
 
-    from bench.lib.weights import make_weights
-    weights = make_weights(spec, jax.random.PRNGKey(_seed32(seed)),
-                           devices[0])
+    weights = arch.make_weights(spec, jax.random.PRNGKey(_seed32(seed)),
+                                devices)
     traffic = loadgen.Traffic(mix, seed, spec.vocab_size)
-    cfg, serve, sched = build(doc, spec, weights)
+    cfg, serve, sched = build(arch, doc, spec, weights)
     warm_up(sched, serve, traffic)
     if fault is not None:
         fault(sched)
     return types.SimpleNamespace(
         root=root, manifest=manifest, workload=workload, seed=seed, doc=doc,
-        mix=mix, spec=spec, chips=chips, devices=devices, counter=counter,
-        weights=weights, traffic=traffic, serve=serve, sched=sched)
+        mix=mix, arch=arch, spec=spec, chips=chips, devices=devices,
+        counter=counter, weights=weights, traffic=traffic, serve=serve,
+        sched=sched)
 
 
 def window(c, seconds: float, trace: bool, keep_trace=None):
@@ -294,7 +314,7 @@ def window(c, seconds: float, trace: bool, keep_trace=None):
     c.counter.armed = False
     before, after = opened["compiles"], sched.compile_stats()
     ctx = types.SimpleNamespace(
-        window=win, spec=c.spec, serve=serve, chips=c.chips,
+        window=win, arch=c.arch, spec=c.spec, serve=serve, chips=c.chips,
         setup_s=opened["setup_s"], seconds=win.seconds,
         device_kind=c.devices[0].device_kind, trace=None, ticks=[],
         trace_window=None, window_loop=mix["loop"], mix=mix,
@@ -345,10 +365,10 @@ def check(c, win, control: bool = False):
         gaps["control"] = -math.inf
     for r in chosen:
         prompt = c.traffic.request(r.index).prompt
-        gaps["program"] = max(gaps["program"], reference.served_gap(
+        gaps["program"] = max(gaps["program"], c.arch.served_gap(
             c.spec, c.weights, prompt, served[r.rid]))
         if control:
-            gaps["control"] = max(gaps["control"], reference.control_gap(
+            gaps["control"] = max(gaps["control"], c.arch.control_gap(
                 c.spec, c.weights, prompt, served[r.rid]))
     return (gaps, len(chosen), sum(len(v) for v in served.values()),
             time.perf_counter() - t0)

@@ -7,20 +7,18 @@ import jax
 import numpy as np
 import pytest
 
-from bench.lib import reference as R
-from bench.lib import spec as S
-from bench.lib.weights import make_weights
+from bench.arch import qwen2 as A
 from harness_util import TINY
 
 
 @pytest.mark.parametrize("seed", [0, 1, 2])
 def test_fp8_control_fails_the_limit(seed):
-    sp = S.model_spec(TINY)
-    w = make_weights(sp, jax.random.PRNGKey(seed))
+    sp = A.model_spec(TINY)
+    w = A.make_weights(sp, jax.random.PRNGKey(seed))
     rng = np.random.default_rng(seed)
     prompt = rng.integers(0, 256, 24).astype(np.int32)
     served = rng.integers(0, 256, 40).astype(np.int32)
-    assert R.control_gap(sp, w, prompt, served) > \
+    assert A.control_gap(sp, w, prompt, served) > \
         TINY["check"]["max_logit_gap"]
 
 

@@ -1,18 +1,18 @@
-"""FLOP and byte counts of bench/lib/flops.py against hand counts, for the
+"""FLOP and byte counts of bench/arch/qwen2.py against hand counts, for the
 benchmark's configuration."""
 
+import json
 from pathlib import Path
 
 import pytest
 
-from bench.lib import flops as F
-from bench.lib import spec as S
+from bench.arch import qwen2 as F
 
 CONFIGS = Path(__file__).resolve().parents[1] / "configs"
 
 
 def _spec(name):
-    return S.model_spec(S.load_doc(CONFIGS / f"{name}.json"))
+    return F.model_spec(json.loads((CONFIGS / f"{name}.json").read_text()))
 
 
 def test_qwen_counts_by_hand():

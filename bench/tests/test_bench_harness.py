@@ -39,6 +39,30 @@ def test_added_config_mix_and_metric_run_by_name(tmp_path):
     assert report["compiles_in_window"] == 0
 
 
+def test_second_architecture_added_by_new_files_only(tmp_path):
+    """A configuration whose ``model_type`` names a module that only the
+    test root holds runs correct, and every file copied from the repository
+    is byte-identical to it: nothing existing was edited."""
+    from bench import run
+
+    root = make_root(tmp_path)
+    res = run.run_cell("tiny2.mix", SEED, 2.0, False, root=root)
+    assert res["correct"] is True, res["compared"]
+    assert res["failed"] == 0
+    assert res["metrics"]["tiny_requests"]["value"] == res["attempted"] > 0
+    copied = [p for p in (REPO / "bench").rglob("*")
+              if p.is_file() and "__pycache__" not in p.parts]
+    assert copied
+    for p in copied:
+        assert (root / p.relative_to(REPO)).read_bytes() == p.read_bytes(), p
+    # the manifest only gains entries
+    m, ours = (json.loads((r / "BENCHMARK.json").read_text())
+               for r in (root, REPO))
+    for key, value in ours.items():
+        assert (m[key][:len(value)] == value if isinstance(value, list)
+                else m[key] == value), key
+
+
 def test_refuses_without_a_chip():
     env = dict(os.environ, JAX_PLATFORMS="cpu")
     p = subprocess.run([sys.executable, "bench/run.py", "--workload",

@@ -9,7 +9,7 @@ import pytest
 
 from bench.lib import trace as T
 from bench.lib.drive import TickRec
-from bench.lib.spec import ModelSpec
+from bench.arch import qwen2
 
 
 def small_trace():
@@ -74,10 +74,11 @@ def test_idle_gaps_named_by_host_span():
 
 
 def _ctx(tr):
-    spec = ModelSpec(name="t", d_model=8, n_layers=2, d_ff=16,
-                     vocab_size=32, n_heads=2, n_kv_heads=1, head_dim=4,
-                     qkv_bias=False, rope_theta=1e4, norm_eps=1e-5,
-                     tie_embeddings=True, dtype="bfloat16")
+    spec = qwen2.ModelSpec(name="t", d_model=8, n_layers=2, d_ff=16,
+                           vocab_size=32, n_heads=2, n_kv_heads=1,
+                           head_dim=4, qkv_bias=False, rope_theta=1e4,
+                           norm_eps=1e-5, tie_embeddings=True,
+                           dtype="bfloat16")
     t1 = TickRec(0.9, 1.7, 2, decode=[(10, 0, 2), (20, 5, 2)], chunk=[],
                  prefill=[], traced=True)
     t2 = TickRec(1.9, 2.6, 2, decode=[(10, 2, 2)], chunk=[(0, 8)],
@@ -85,8 +86,9 @@ def _ctx(tr):
     t1.a, t1.b, t2.a, t2.b = 0.9, 1.7, 1.9, 2.6
     serve = types.SimpleNamespace(tick_steps=2, max_slots=4)
     return types.SimpleNamespace(trace=tr, ticks=[t1, t2],
-                                 trace_window=(0.8, 2.6), spec=spec,
-                                 serve=serve, device_kind="TPU v5 lite",
+                                 trace_window=(0.8, 2.6), arch=qwen2,
+                                 spec=spec, serve=serve,
+                                 device_kind="TPU v5 lite",
                                  window=types.SimpleNamespace(
                                      ticks=[t1, t2], end=3.0))
 
